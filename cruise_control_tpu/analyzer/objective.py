@@ -100,6 +100,20 @@ class GoalChain:
             obj = (w * violations).sum() + TIE_WEIGHT * min(self.weights) * scores.sum()
         return obj, violations, scores
 
+    def objective_f64(self, violations, scores) -> np.ndarray:
+        """`evaluate`'s objective recomposed on the host in float64 from
+        its f32 per-goal terms (any leading batch axes).  Reports that
+        compare states use this: under one hard violation the f32 sum
+        sits near 625, where its ulp (6.1e-5) exceeds the weight of every
+        goal ranked below the tenth, so two states that differ only in
+        those goals would read as equal."""
+        v = np.asarray(violations, np.float64)
+        s = np.asarray(scores, np.float64)
+        return (
+            v @ np.asarray(self.weights, np.float64)
+            + TIE_WEIGHT * min(self.weights) * s.sum(-1)
+        )
+
     def hard_mask(self) -> np.ndarray:
         return np.asarray([g.hard for g in self.goals])
 

@@ -1,8 +1,7 @@
 """Boot prewarm manifest + AOT-serialized engine programs.
 
-The warm-up wall: steady-state device wall is ~6.5 s, but every process
-restart pays 15-180 s of Python tracing + XLA compile before the first
-proposal (BENCH_r03-r05) — the persistent XLA cache (PR 9,
+The warm-up wall: every process restart pays Python tracing + XLA
+compile before the first proposal — the persistent XLA cache (PR 9,
 common/compilation_cache.py) skips the compile but not the tracing, and
 only once a proposal pass happens to request that bucket.  This module
 closes both gaps:

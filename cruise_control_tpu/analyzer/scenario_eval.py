@@ -137,16 +137,16 @@ class ScenarioEvaluator:
             )
         sup = self.supervisor
         if sup is None:
-            obj, viol = self._evaluate_on_device(states)
-            return obj, viol, False
+            viol, scores = self._evaluate_on_device(states)
+            return self.chain.objective_f64(viol, scores), viol, False
         from cruise_control_tpu.common.device_watchdog import DeviceDegradedError
 
         if sup.available():
             try:
-                obj, viol = sup.call(
+                viol, scores = sup.call(
                     lambda: self._evaluate_on_device(states), op="scenario-eval"
                 )
-                return obj, viol, False
+                return self.chain.objective_f64(viol, scores), viol, False
             except DeviceDegradedError:
                 pass
         obj, viol = self._evaluate_cpu(states)
@@ -177,52 +177,66 @@ class ScenarioEvaluator:
                 varying[f] = jnp.asarray(np.stack([np.asarray(v) for v in vals]))
         if not varying:
             # every scenario is the identity: score the base once, fan out
-            obj, viol = self._single_eval(states[0])
-            return (
-                np.full(len(states), float(obj), np.float64),
-                np.tile(np.asarray(viol, np.float64), (len(states), 1)),
-            )
+            viol, scores = self._single_terms(states[0])
+            n = len(states)
+            return np.tile(viol, (n, 1)), np.tile(scores, (n, 1))
         key = (shape, len(states), frozenset(varying))
         with self._fns_lock:
             fn = self._batched_fns.get(key)
             if fn is not None:
                 self._batched_fns.move_to_end(key)
         if fn is None:
-            chain, constraint = self.chain, self.constraint
-
-            def batched(shared, varying):
-                def one(diff):
-                    s = ClusterState(shape=shape, **shared, **diff)
-                    obj, viol, _ = chain.evaluate(s, constraint=constraint)
-                    return obj, viol
-
-                # lax.map, not vmap: the goal chain is segment-sum heavy,
-                # and batching scatters adds a batch dimension XLA lowers
-                # poorly (CPU measurably WORSE than sequential).  lax.map
-                # compiles the single-state program once and loops it on
-                # device — identical per-scenario numerics (pinned by the
-                # scenarios bench gate), one dispatch, one host sync.
-                return jax.lax.map(one, varying)
-
-            fn = jax.jit(batched)
+            fn = self.batch_program(shape)
             with self._fns_lock:
                 self._batched_fns[key] = fn
                 while len(self._batched_fns) > self._batched_fns_cap:
                     self._batched_fns.popitem(last=False)
-        obj, viol = jax.device_get(fn(shared, varying))
-        return np.asarray(obj, np.float64), np.asarray(viol, np.float64)
+        viol, scores = jax.device_get(fn(shared, varying))
+        return np.asarray(viol, np.float64), np.asarray(scores, np.float64)
 
-    def _single_eval(self, state):
+    def batch_program(self, shape):
+        """The jitted batch scorer for states of `shape`: (shared fields,
+        varying fields with a leading batch axis) -> (violations [N, G],
+        scores [N, G])."""
         import jax
 
-        if getattr(self, "_single_fn", None) is None:
+        chain, constraint = self.chain, self.constraint
+
+        def batched(shared, varying):
+            def one(diff):
+                s = ClusterState(shape=shape, **shared, **diff)
+                _, viol, scores = chain.evaluate(s, constraint=constraint)
+                return viol, scores
+
+            # lax.map, not vmap: the goal chain is segment-sum heavy,
+            # and batching scatters adds a batch dimension XLA lowers
+            # poorly (CPU measurably WORSE than sequential).  lax.map
+            # compiles the single-state program once and loops it on
+            # device — identical per-scenario numerics (pinned by the
+            # scenarios bench gate), one dispatch, one host sync.
+            return jax.lax.map(one, varying)
+
+        return jax.jit(batched)
+
+    def _single_terms(self, state):
+        """(violations f64[G], scores f64[G]) of one state, jitted."""
+        import jax
+
+        if self._single_fn is None:
 
             def one(s):
-                obj, viol, _ = self.chain.evaluate(s, constraint=self.constraint)
-                return obj, viol
+                _, viol, scores = self.chain.evaluate(s, constraint=self.constraint)
+                return viol, scores
 
             self._single_fn = jax.jit(one)
-        return jax.device_get(self._single_fn(state))
+        viol, scores = jax.device_get(self._single_fn(state))
+        return np.asarray(viol, np.float64), np.asarray(scores, np.float64)
+
+    def _single_eval(self, state):
+        """(objective, violations f64[G]) of one state — the sequential
+        twin of the batched program, composed the same way."""
+        viol, scores = self._single_terms(state)
+        return float(self.chain.objective_f64(viol, scores)), viol
 
     # ------------------------------------------------------------------
     # calibration scoring (decision ledger, analyzer/ledger.py)
@@ -289,18 +303,19 @@ class ScenarioEvaluator:
         if self._cpu_fn is None:
 
             def one(s):
-                obj, viol, _ = self.chain.evaluate(s, constraint=self.constraint)
-                return obj, viol
+                _, viol, scores = self.chain.evaluate(s, constraint=self.constraint)
+                return viol, scores
 
             self._cpu_fn = jax.jit(one)
-        objs, viols = [], []
+        viols, scores = [], []
         with jax.default_device(cpu):
             for s in states:
                 host = jax.tree.map(np.asarray, s)
-                o, v = jax.device_get(self._cpu_fn(host))
-                objs.append(float(o))
+                v, sc = jax.device_get(self._cpu_fn(host))
                 viols.append(np.asarray(v, np.float64))
-        return np.asarray(objs, np.float64), np.stack(viols)
+                scores.append(np.asarray(sc, np.float64))
+        viols = np.stack(viols)
+        return self.chain.objective_f64(viols, np.stack(scores)), viols
 
     # ------------------------------------------------------------------
     # the full planner pass

@@ -2,8 +2,8 @@
 
 The flight recorder (common/trace.py) lives in process memory, so the one
 failure that matters most — a process hung inside an XLA program and
-killed by the driver (MULTICHIP_r05: bare rc=124, one JAX platform
-warning) — leaves no trace at all.  This module is the aircraft-style
+killed at its time limit (a bare rc=124 with one JAX platform warning)
+— leaves no trace at all.  This module is the aircraft-style
 black box for device work: every device dispatch writes a line-JSONL
 record to an on-disk spool BEFORE the call can block, so a hang, a
 kill -9 or an OOM-kill leaves a readable trail ending at the exact

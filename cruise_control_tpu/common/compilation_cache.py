@@ -1,11 +1,17 @@
 """Persistent XLA compilation cache.
 
 The engine's statics-as-arguments design already avoids recompiles WITHIN a
-process (analyzer/engine.py module docstring), but a service restart used to
-pay the full ~70s warm-up again (BENCH_r01 warmup_s).  JAX's persistent
-compilation cache writes compiled executables to disk keyed by HLO
-fingerprint, so a restarted service (same shapes, same jax/XLA version)
-reloads them in milliseconds.
+process (analyzer/engine.py module docstring), but a restarted service
+would pay every compile again.  JAX's persistent compilation cache writes
+compiled executables to disk keyed by HLO fingerprint, so a restarted
+service (same shapes, same jax/XLA version) reloads them in milliseconds.
+
+Placement: where the environment sets JAX_COMPILATION_CACHE_DIR, JAX reads
+it itself and this module sets no directory of its own.  Otherwise the
+cache lives at one fixed, git-ignored path inside the checkout
+(DEFAULT_CACHE_DIR), never under a temp name: a later process finds its
+entries only at the same path, and a machine that copies the checkout
+copies nothing else.
 
 Boot observability (config tpu.compile.cache.dir): enabling the cache
 records its on-disk entry inventory; `boot_report()` later diffs against
@@ -25,6 +31,14 @@ import os
 import threading
 
 log = logging.getLogger(__name__)
+
+#: the environment variable JAX itself reads the cache directory from
+ENV_CACHE_DIR = "JAX_COMPILATION_CACHE_DIR"
+#: the cache's home when the environment names none: inside the checkout
+DEFAULT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
+    ".jax_cache",
+)
 
 _enabled = False
 #: entry names present on disk when the cache was enabled (boot inventory)
@@ -88,40 +102,54 @@ def _scan(cache_dir: str) -> tuple[set[str], int]:
     return entries, total
 
 
-def enable_persistent_cache(cache_dir: str | None = None) -> str | None:
-    """Idempotently point JAX at a durable on-disk compilation cache.
+def resolve_cache_dir(configured: str | None) -> str | None:
+    """The cache directory a deployment uses: JAX_COMPILATION_CACHE_DIR
+    when the environment sets it, else `configured`, where None means
+    DEFAULT_CACHE_DIR and an empty string disables the cache."""
+    env = os.environ.get(ENV_CACHE_DIR)
+    if env:
+        return env
+    if configured is None:
+        return DEFAULT_CACHE_DIR
+    return os.path.expanduser(configured) or None
 
-    Returns the directory used, or None when disabled (empty dir given or
-    an old jax without the feature).  Logs the boot inventory — how many
-    cached executables a restart can reload instead of re-tracing.
+
+def enable_persistent_cache(cache_dir: str | None) -> str | None:
+    """Idempotently turn on JAX's durable on-disk compilation cache.
+
+    JAX_COMPILATION_CACHE_DIR, when set, wins over `cache_dir`, and then
+    no directory is set in code.  Returns the directory used, or None
+    when disabled (no directory from either).  Logs the boot inventory —
+    how many cached executables a restart can reload instead of
+    re-tracing.
     """
     global _enabled, _boot_entries, _cache_dir
+    env = os.environ.get(ENV_CACHE_DIR)
+    cache_dir = env or cache_dir
     if not cache_dir:
         return None
-    cache_dir = os.path.expanduser(cache_dir)
     if _enabled:
-        return cache_dir
-    try:
-        import jax
+        return _cache_dir
+    import jax
 
-        os.makedirs(cache_dir, exist_ok=True)
+    cache_dir = os.path.expanduser(cache_dir)
+    os.makedirs(cache_dir, exist_ok=True)
+    if not env:
         jax.config.update("jax_compilation_cache_dir", cache_dir)
-        # persist even sub-second compiles: a cold process pays dozens of
-        # 0.1-0.5s "tiny" compiles (zero-fills, reductions) that add whole
-        # seconds to warmup; disk hits are ~ms
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.05)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-        _enabled = True
-        _cache_dir = cache_dir
-        _boot_entries, total = _scan(cache_dir)
-        log.info(
-            "persistent XLA compile cache at %s: %d cached executables "
-            "(%.1f MB) available warm at boot",
-            cache_dir, len(_boot_entries), total / 1e6,
-        )
-        return cache_dir
-    except Exception:  # pragma: no cover — very old jax
-        return None
+    # persist even sub-second compiles: a cold process pays dozens of
+    # 0.1-0.5s "tiny" compiles (zero-fills, reductions) that add whole
+    # seconds to warmup; disk hits are ~ms
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.05)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    _enabled = True
+    _cache_dir = cache_dir
+    _boot_entries, total = _scan(cache_dir)
+    log.info(
+        "persistent XLA compile cache at %s: %d cached executables "
+        "(%.1f MB) available warm at boot",
+        cache_dir, len(_boot_entries), total / 1e6,
+    )
+    return cache_dir
 
 
 def boot_report() -> dict | None:

@@ -187,10 +187,11 @@ def _analyzer_defs() -> ConfigDef:
              "max compiled engines kept per optimizer (LRU; evicted "
              "engines' device buffers are released) — bounds HBM growth "
              "across shape-bucket transitions", in_range(lo=1), group=g)
-    d.define("tpu.compilation.cache.dir", T.STRING,
-             "~/.cache/cruise_control_tpu/xla", I.LOW,
-             "persistent XLA compilation cache directory; empty disables "
-             "(compiled programs survive service restarts)", group=g)
+    d.define("tpu.compilation.cache.dir", T.STRING, None, I.LOW,
+             "persistent XLA compilation cache directory (compiled programs "
+             "survive service restarts); JAX_COMPILATION_CACHE_DIR in the "
+             "environment wins, unset means .jax_cache inside the checkout, "
+             "empty disables", group=g)
     d.define("tpu.compile.cache.dir", T.STRING, None, I.LOW,
              "preferred spelling of tpu.compilation.cache.dir (takes "
              "precedence when both are set): the on-disk XLA executable "
@@ -209,7 +210,7 @@ def _analyzer_defs() -> ConfigDef:
     d.define("tpu.supervisor.op.timeout.s", T.DOUBLE, 300.0, I.MEDIUM,
              "hard wall-clock budget per supervised engine invocation; a "
              "call not finished by then is classified as a device HANG "
-             "(observed MULTICHIP_r05: a wedged runtime hangs every op)",
+             "(observed: a wedged runtime hangs every op)",
              in_range(lo=0.001), group=g)
     d.define("tpu.supervisor.max.retries", T.INT, 2, I.LOW,
              "retries (with jittered backoff) for TRANSIENT-classified "
@@ -1364,14 +1365,17 @@ class CruiseControlConfig(AbstractConfig):
 
     def compile_cache_dir(self) -> str | None:
         """Persistent XLA compile-cache directory: the preferred
-        tpu.compile.cache.dir when SET (an explicitly empty value
-        disables the cache — it must not fall through to the legacy
-        key's non-empty default), else the legacy
-        tpu.compilation.cache.dir (empty/None disables)."""
+        tpu.compile.cache.dir when SET, else the legacy
+        tpu.compilation.cache.dir, resolved by
+        compilation_cache.resolve_cache_dir (JAX_COMPILATION_CACHE_DIR
+        wins; unset means the fixed directory inside the checkout; an
+        explicitly empty value disables)."""
+        from cruise_control_tpu.common.compilation_cache import resolve_cache_dir
+
         v = self.get("tpu.compile.cache.dir")
-        if v is not None:
-            return v or None
-        return self.get("tpu.compilation.cache.dir") or None
+        if v is None:
+            v = self.get("tpu.compilation.cache.dir")
+        return resolve_cache_dir(v)
 
     def prewarm_manifest_dir(self) -> str | None:
         """Directory of the boot-prewarm manifest + AOT artifacts, or

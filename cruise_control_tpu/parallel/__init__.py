@@ -21,7 +21,7 @@ from cruise_control_tpu.parallel.mesh import (
     grid_mesh,
     model_mesh,
     normalize_mesh,
-    shard_map_compat,
+    shard_map_unchecked,
 )
 from cruise_control_tpu.parallel.model_shard import ShardPlan
 from cruise_control_tpu.parallel.portfolio import portfolio_run
@@ -39,5 +39,5 @@ __all__ = [
     "model_mesh",
     "normalize_mesh",
     "portfolio_run",
-    "shard_map_compat",
+    "shard_map_unchecked",
 ]

@@ -51,7 +51,7 @@ with one psum (``parallel/model_shard._ModelShardEngine``).  Per-chip
 model memory and per-step O(R)/O(P) FLOPs drop ~1/n — the mode that
 carries 25k brokers / 2M partitions on an 8-chip mesh.  Unlike the
 replaced rounds-1-5 ``parallel/sharded.py`` design (per-shard RNG
-streams, no 1-vs-N parity, ~22% slower at n=1 — VERDICT r5 item 4), the
+streams, no 1-vs-N parity, ~22% slower at n=1), the
 sharded-model mode keeps every RNG draw replicated, so placements stay
 byte-identical to the replicated mesh whenever the psum'd objective
 partials are exact (integer-quantized loads; float loads track to ulp).
@@ -108,23 +108,14 @@ MODEL_AXIS = "model"
 log = logging.getLogger(__name__)
 
 
-def shard_map_compat(fn, mesh, in_specs, out_specs):
-    """The ONE dual-import shard_map shim every mesh caller uses.
-
-    jax >= 0.4.35 exposes shard_map at top level with `check_vma`; older
-    releases keep it in jax.experimental with `check_rep`.  Consolidated
-    here (it used to be copy-pasted per parallel module) so a jax upgrade
-    is one edit."""
-    try:
-        from jax import shard_map
-
-        return shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                         check_vma=False)
-    except (ImportError, TypeError):  # older jax
-        from jax.experimental.shard_map import shard_map
-
-        return shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                         check_rep=False)
+def shard_map_unchecked(fn, mesh, in_specs, out_specs):
+    """`jax.shard_map` with the replication (vma) check off: every mesh
+    program here ends in conflict resolution that runs identically on
+    each device after a gather or psum, so its outputs are replicated by
+    construction, which the checker cannot prove."""
+    return jax.shard_map(
+        fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
+    )
 
 
 def model_mesh(devices=None) -> Mesh:
@@ -366,6 +357,11 @@ class MeshEngine:
                 is_leaf=lambda x: isinstance(x, P),
             )
             self.statics = jax.device_put(self.engine.statics, shardings)
+            # the engine built its statics whole on the default device;
+            # keeping that copy would leave one device holding the entire
+            # model beside its slice.  The sharded copy takes its place
+            # (later readers need only its avals and layout).
+            self.engine.statics = self._twin.statics = self.statics
         else:
             self.statics = jax.device_put(
                 self.engine.statics, NamedSharding(self.mesh, P())
@@ -419,7 +415,7 @@ class MeshEngine:
     def _build_jits(self) -> None:
         spec_r = P(RESTART_AXIS)
         self._jit_init = jax.jit(
-            shard_map_compat(
+            shard_map_unchecked(
                 self._init_fn, self.mesh,
                 in_specs=(self._sx_specs, spec_r), out_specs=self._carry_specs,
             )
@@ -427,7 +423,7 @@ class MeshEngine:
         # the fused whole-anneal program; the carry is DONATED so each
         # restart chain holds one placement copy in HBM
         self._jit_run = jax.jit(
-            shard_map_compat(
+            shard_map_unchecked(
                 self._run_fn, self.mesh,
                 in_specs=(self._sx_specs, self._carry_specs),
                 out_specs=(self._carry_specs, spec_r, spec_r),
@@ -487,7 +483,7 @@ class MeshEngine:
         sharded variants' tracing overlaps the caller's serial prelude
         exactly like the single-device warm start.  No AOT artifacts
         here: shard_map'd programs bake mesh/sharding state that the
-        round-4 export cache got wrong (VERDICT r4) — the mesh path warms
+        round-4 export cache got wrong — the mesh path warms
         by overlap only, at the given pool `priority`."""
         if self._warm_futures is not None:
             return
@@ -617,7 +613,7 @@ class MeshEngine:
         if verbose:
             if self._jit_run_verbose is None:
                 self._jit_run_verbose = jax.jit(
-                    shard_map_compat(
+                    shard_map_unchecked(
                         self._run_verbose_fn, self.mesh,
                         in_specs=(self._sx_specs, self._carry_specs),
                         out_specs=(
@@ -705,7 +701,7 @@ class MeshEngine:
         if fn is None:
             spec_r = P(RESTART_AXIS)
             fn = jax.jit(
-                shard_map_compat(
+                shard_map_unchecked(
                     partial(self._seg_slice_fn, L), self.mesh,
                     in_specs=(self._sx_specs, self._carry_specs, spec_r, P()),
                     out_specs=(self._carry_specs, spec_r, spec_r),
@@ -789,7 +785,7 @@ class MeshEngine:
             )
             if self._jit_seg_init_mesh is None:
                 self._jit_seg_init_mesh = jax.jit(
-                    shard_map_compat(
+                    shard_map_unchecked(
                         self._seg_init_fn, self.mesh,
                         in_specs=(self._sx_specs, P(RESTART_AXIS)),
                         out_specs=(self._carry_specs, P(RESTART_AXIS)),
@@ -851,7 +847,7 @@ class MeshEngine:
             )
         if self._jit_obj is None:
             self._jit_obj = jax.jit(
-                shard_map_compat(
+                shard_map_unchecked(
                     self._obj_fn, self.mesh,
                     in_specs=(self._sx_specs, self._carry_specs),
                     out_specs=P(RESTART_AXIS),
@@ -954,7 +950,7 @@ class MeshEngine:
             temps = temps[None]
         if self._jit_schedule is None:
             self._jit_schedule = jax.jit(
-                shard_map_compat(
+                shard_map_unchecked(
                     self._schedule_fn, self.mesh,
                     in_specs=(self._sx_specs, self._carry_specs, P()),
                     out_specs=(

@@ -71,7 +71,7 @@ def stable_grouped_order(seg: jax.Array, n_keys: int) -> jax.Array:
     ``[0, n_keys)``.  ``jnp.argsort`` lowers to a variadic (two-operand)
     ``lax.sort``; on the pinned jax/XLA build the CPU backend miscompiles
     variadic sorts of shard-varying operands inside a
-    ``shard_map(check_rep=False)`` program whose results feed a
+    ``shard_map(check_vma=False)`` program whose results feed a
     ``lax.scan`` — every device silently receives device 0's sort output
     (tests/test_model_shard.py::test_variadic_sort_miscompile_guard keeps
     a minimal repro pinned).  Single-operand sorts are unaffected, so the

@@ -14,7 +14,7 @@ parallel/mesh.py, shared verbatim with grid.py and portfolio.py.  The
 pre-round-6 replica/partition-axis sharding implementation that used to
 live here (per-shard RNG streams, psum'd aggregate refresh) was replaced —
 it made 1-vs-N parity impossible and ran ~22% slower than the plain engine
-at n=1 (VERDICT r5 item 4).  Replica/partition-axis sharding now exists as
+at n=1.  Replica/partition-axis sharding now exists as
 the mesh engine's sharded-MODEL mode (parallel/model_shard.py +
 ``MeshEngine(model_shard_min_partitions=...)``), which keeps every RNG
 draw replicated and resolves row gathers by ownership psums — parity
@@ -30,10 +30,10 @@ from cruise_control_tpu.parallel.mesh import (
     MODEL_AXIS,
     MeshEngine,
     model_mesh,
-    shard_map_compat,
+    shard_map_unchecked,
 )
 
-__all__ = ["MODEL_AXIS", "ShardedEngine", "model_mesh", "shard_map_compat"]
+__all__ = ["MODEL_AXIS", "ShardedEngine", "model_mesh", "shard_map_unchecked"]
 
 
 class ShardedEngine(MeshEngine):

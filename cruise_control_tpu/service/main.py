@@ -416,6 +416,8 @@ def build_simulated_service(
     topics: dict[str, int] | None = None,
     seed: int = 0,
     sampled_windows: int = 3,
+    num_racks: int = 3,
+    replication: int = 2,
 ):
     """Full in-process service against the simulated cluster (the embedded
     harness analog, reference CruiseControlIntegrationTestHarness)."""
@@ -439,8 +441,13 @@ def build_simulated_service(
             "tpu.num.rounds": 2,
         }
     )
-    topo = synthetic_topology(num_brokers=num_brokers, topics=topics or {"T0": 12, "T1": 12},
-                              seed=seed)
+    topo = synthetic_topology(
+        num_brokers=num_brokers,
+        num_racks=num_racks,
+        topics=topics or {"T0": 12, "T1": 12},
+        replication=replication,
+        seed=seed,
+    )
     metadata = StaticMetadataProvider(topo)
     admin = SimulatedClusterAdmin(metadata, link_rate_bytes_per_s=1e12)
     sampler = SyntheticWorkloadSampler(topo, seed=seed)

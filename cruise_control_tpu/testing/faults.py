@@ -14,7 +14,7 @@ Two injection surfaces:
     watchdog's trivial-op probe) routes through ONE process-wide hook
     (common/device_watchdog.set_device_op_hook).  `device_fault` installs
     an interceptor on that seam; `device_wedged` is the composite that
-    models the observed failure (MULTICHIP_r05): EVERY device op —
+    models the observed failure (a hung dispatch): EVERY device op —
     including the recovery probe — blocks until the context exits.
   * arbitrary methods — `method_fault` (with the `slow` / `hanging` /
     `raising` / `dropping` effects) patches a bound method on any object
@@ -252,7 +252,7 @@ def device_slowdown(
 ):
     """Sustained device SLOWNESS: every targeted engine op completes for
     real, then stalls until its wall clock has been scaled by `factor`
-    (>= 1.0) — thermal throttling, a contended tunnel, a neighbour's
+    (>= 1.0) — thermal throttling, a contended host link, a neighbour's
     burst.  Hangs and crashes were injectable before; this is the shape
     overload soaks need: the device keeps answering, just too slowly to
     hold the fleet's deadlines, so shedding/brownout must engage rather
@@ -278,7 +278,7 @@ def device_slowdown(
 
 @contextlib.contextmanager
 def device_wedged(*, ops=ALL_DEVICE_OPS, schedule: FaultSchedule = ALWAYS):
-    """The observed MULTICHIP_r05 failure: every device op — engine runs
+    """The observed hung-dispatch failure: every device op — engine runs
     AND the recovery probe — hangs until the context exits ("the fault
     clears").  Abandoned supervisor threads unblock at exit and complete
     against the real device, so nothing leaks into the next test."""

@@ -1,7 +1,7 @@
 #!/usr/bin/env bash
 # Round gate: the full test suite + the multi-chip dryrun must BOTH pass
-# before a round ends (VERDICT r4: round 4 shipped a red suite because
-# nothing forced a final full run).  Reference analog: the CircleCI gate
+# before a round ends (round 4 shipped a red suite because nothing
+# forced a final full run).  Reference analog: the CircleCI gate
 # running `./gradlew clean build` (.circleci/config.yml:16).
 #
 # Usage: scripts/check.sh [pytest-args...]
@@ -14,7 +14,7 @@ python -m pytest tests/ -q "$@"
 suite_rc=$?
 
 echo "== check.sh: dryrun_multichip(8) on virtual CPU mesh =="
-GRAFT_FORCE_CPU=1 XLA_FLAGS="--xla_force_host_platform_device_count=8" \
+JAX_PLATFORMS=cpu XLA_FLAGS="--xla_force_host_platform_device_count=8" \
 python - <<'EOF'
 import __graft_entry__ as g
 g.dryrun_multichip(8)
@@ -23,7 +23,7 @@ EOF
 dryrun_rc=$?
 
 echo "== check.sh: single-chip entry compile check =="
-GRAFT_FORCE_CPU=1 python - <<'EOF'
+JAX_PLATFORMS=cpu python - <<'EOF'
 import jax, __graft_entry__ as g
 fn, args = g.entry()
 out = jax.jit(fn)(*args)
@@ -33,7 +33,7 @@ EOF
 entry_rc=$?
 
 echo "== check.sh: bench.py --smoke (fused vs legacy perf path, CPU) =="
-GRAFT_FORCE_CPU=1 python bench.py --smoke
+JAX_PLATFORMS=cpu python bench.py --smoke
 smoke_rc=$?
 
 echo "== check.sh: bench.py --mesh-smoke (1-vs-8-device mesh parity, CPU) =="
@@ -41,7 +41,7 @@ echo "== check.sh: bench.py --mesh-smoke (1-vs-8-device mesh parity, CPU) =="
 # anneal must reproduce the plain engine's placements byte-for-byte, and
 # the per-round collective payload must match the gather-candidates-only
 # schedule (0 bytes at n=1) — the mesh engine layer's core invariants
-GRAFT_FORCE_CPU=1 XLA_FLAGS="--xla_force_host_platform_device_count=8" \
+JAX_PLATFORMS=cpu XLA_FLAGS="--xla_force_host_platform_device_count=8" \
 python bench.py --mesh-smoke
 mesh_rc=$?
 
@@ -52,19 +52,19 @@ echo "== check.sh: bench.py --mesh --smoke (sharded-model mesh at 25k/2M, CPU) =
 # at the 25k-broker / 2M-partition scale-out north star (full geometry,
 # shrunken search) — scaling efficiency + collective bytes are recorded
 # in BENCH_mesh_r01.json
-GRAFT_FORCE_CPU=1 XLA_FLAGS="--xla_force_host_platform_device_count=8" \
+JAX_PLATFORMS=cpu XLA_FLAGS="--xla_force_host_platform_device_count=8" \
 python bench.py --mesh --smoke
 mesh_model_rc=$?
 
 echo "== check.sh: bench.py --churn --smoke (shape-bucketed serving, CPU) =="
-GRAFT_FORCE_CPU=1 python bench.py --churn --smoke
+JAX_PLATFORMS=cpu python bench.py --churn --smoke
 churn_rc=$?
 
 echo "== check.sh: bench.py --scenarios --smoke (batched what-if evaluation, CPU) =="
 # named gate: one batched N-scenario evaluation must be no slower than N
 # sequential runs AND produce bit-identical per-scenario objectives —
 # batching is an execution detail of the planner, never a numerics change
-GRAFT_FORCE_CPU=1 python bench.py --scenarios --smoke
+JAX_PLATFORMS=cpu python bench.py --scenarios --smoke
 scenarios_rc=$?
 
 echo "== check.sh: bench.py --streaming --smoke (incremental controller replay, CPU) =="
@@ -79,7 +79,7 @@ echo "== check.sh: bench.py --streaming --smoke (incremental controller replay, 
 # proved by the dispatch meter) with a sub-second
 # window-roll-to-publish p99 (cold-compile cycles excluded via their
 # one-shot sensors)
-GRAFT_FORCE_CPU=1 python bench.py --streaming --smoke
+JAX_PLATFORMS=cpu python bench.py --streaming --smoke
 streaming_rc=$?
 
 echo "== check.sh: streaming controller gate (prior parity, warm start, delta path) =="
@@ -96,7 +96,7 @@ echo "== check.sh: bench.py --coldstart --smoke (restart SLO: manifest+AOT prewa
 # ZERO fresh engine traces for manifest-listed buckets, a strictly
 # lower cold-start-to-first-proposal wall than truly-cold, and the
 # identical objective (the AOT path must never change results)
-GRAFT_FORCE_CPU=1 python bench.py --coldstart --smoke
+JAX_PLATFORMS=cpu python bench.py --coldstart --smoke
 coldstart_rc=$?
 
 echo "== check.sh: cold-start prewarm gate (manifest, AOT fallback ladder, warm pool) =="
@@ -113,7 +113,7 @@ echo "== check.sh: bench.py --fleet-smoke (shared-engine fleet economics, CPU) =
 # FEWER compiled engines than clusters (the shared AnalyzerCore is real)
 # and each cluster's warm proposal wall within 1.5x a single-cluster
 # baseline — multi-tenancy must not tax steady-state serving
-GRAFT_FORCE_CPU=1 python bench.py --fleet-smoke
+JAX_PLATFORMS=cpu python bench.py --fleet-smoke
 fleet_smoke_rc=$?
 
 echo "== check.sh: device scheduler gate (QoS classes, preemption, shed/brownout, parity) =="
@@ -140,7 +140,7 @@ echo "== check.sh: bench.py --ha-smoke (lease takeover SLO, CPU) =="
 # named gate: 2 instances over 3 synthetic clusters sharing one lease
 # store — kill one, time-to-takeover-to-first-proposal under budget and
 # the single-holder invariant checked from the lease-store audit trail
-GRAFT_FORCE_CPU=1 python bench.py --ha-smoke
+JAX_PLATFORMS=cpu python bench.py --ha-smoke
 ha_smoke_rc=$?
 
 echo "== check.sh: fleet controller gate (N clusters, shared core, isolation) =="
@@ -182,7 +182,7 @@ echo "== check.sh: bench.py --mesh-chaos --smoke (mid-anneal device loss, CPU) =
 # with placements BYTE-EQUAL to a clean uninterrupted run, checkpoint-off
 # must keep the dispatch stream byte-for-byte with zero extra dispatches,
 # and exactly one MESH_DEGRADED event must arm per degrade episode
-GRAFT_FORCE_CPU=1 XLA_FLAGS="--xla_force_host_platform_device_count=8" \
+JAX_PLATFORMS=cpu XLA_FLAGS="--xla_force_host_platform_device_count=8" \
 python bench.py --mesh-chaos --smoke
 mesh_chaos_rc=$?
 
@@ -199,7 +199,7 @@ echo "== check.sh: /metrics exposition lint gate (live scrape) =="
 # and lint the body with the strict exposition parser (TYPE lines, label
 # escaping, counter monotonicity, histogram bucket structure) — a
 # malformed exposition breaks every dashboard silently
-GRAFT_FORCE_CPU=1 python - <<'EOF'
+JAX_PLATFORMS=cpu python - <<'EOF'
 import urllib.request
 
 from cruise_control_tpu.common.exposition import parse_exposition
@@ -232,7 +232,7 @@ metrics_rc=$?
 echo "== check.sh: trace overhead gate (tracing-on adds <2% to a smoke run) =="
 # named gate: the flight recorder is ON by default on the hot proposal
 # path, so its cost is pinned by measurement, not assumption
-GRAFT_FORCE_CPU=1 python bench.py --trace-overhead
+JAX_PLATFORMS=cpu python bench.py --trace-overhead
 overhead_rc=$?
 
 echo "== check.sh: black-box overhead gate (spool-on adds <2%, disabled path writes nothing) =="
@@ -240,7 +240,7 @@ echo "== check.sh: black-box overhead gate (spool-on adds <2%, disabled path wri
 # a durable dir exists; its per-dispatch write+flush must stay
 # unmeasurable beside an engine run, recording must not perturb results
 # (byte-identical placements), and the disabled path must write zero bytes
-GRAFT_FORCE_CPU=1 python bench.py --blackbox-overhead
+JAX_PLATFORMS=cpu python bench.py --blackbox-overhead
 blackbox_overhead_rc=$?
 
 echo "== check.sh: ledger overhead gate (diagnostics+ledger on adds <2%, byte-identical placements) =="
@@ -248,7 +248,7 @@ echo "== check.sh: ledger overhead gate (diagnostics+ledger on adds <2%, byte-id
 # default; the per-run decision record and the diagnostics-on fused
 # program must stay unmeasurable beside an engine run, placements must be
 # byte-identical on vs off, and the disabled path must write zero bytes
-GRAFT_FORCE_CPU=1 python bench.py --ledger-overhead
+JAX_PLATFORMS=cpu python bench.py --ledger-overhead
 ledger_overhead_rc=$?
 
 echo "== check.sh: decision ledger gate (durability, joins, calibration, /explain) =="

@@ -11,9 +11,9 @@ import time
 
 sys.path.insert(0, "/root/repo")
 
-from cruise_control_tpu.common.compilation_cache import enable_persistent_cache
+from cruise_control_tpu.common.compilation_cache import DEFAULT_CACHE_DIR, enable_persistent_cache
 
-enable_persistent_cache(os.environ.get("BENCH_COMPILE_CACHE", "~/.cache/cruise_control_tpu/xla"))
+enable_persistent_cache(os.environ.get("BENCH_COMPILE_CACHE", DEFAULT_CACHE_DIR))
 
 import jax
 import jax.numpy as jnp

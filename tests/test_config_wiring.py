@@ -1,5 +1,5 @@
 """Config-key wiring tests: every key added for reference parity must
-actually change behavior (VERDICT r2 missing #6 — config-key surface).
+actually change behavior (the config-key surface).
 
 Reference anchors: config/constants/AnomalyDetectorConfig.java,
 ExecutorConfig.java, AnalyzerConfig.java.

@@ -604,7 +604,7 @@ def test_controller_config_keys_parse_and_gate_construction():
         "tpu.compile.cache.dir": "/tmp/a", "tpu.compilation.cache.dir": "/tmp/b",
     })
     assert cfg2.compile_cache_dir() == "/tmp/a"
-    assert CruiseControlConfig({}).compile_cache_dir() is not None  # legacy default
+    assert CruiseControlConfig({}).compile_cache_dir() is not None  # in-checkout default
 
 
 # ------------------------------------------------------------ fused cycle

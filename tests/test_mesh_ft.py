@@ -393,7 +393,7 @@ _MESH_KILL_CHILD = textwrap.dedent("""
         os.environ.get("XLA_FLAGS", "")
         + " --xla_force_host_platform_device_count=8"
     )
-    import jax; jax.config.update("jax_platforms", "cpu")
+    import jax
     import numpy as np
 
     from cruise_control_tpu.analyzer import DEFAULT_CHAIN, OptimizerConfig

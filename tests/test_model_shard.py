@@ -18,7 +18,7 @@ from cruise_control_tpu.analyzer.engine import Engine, OptimizerConfig
 from cruise_control_tpu.analyzer.objective import DEFAULT_CHAIN
 from cruise_control_tpu.models.builder import pad_state
 from cruise_control_tpu.models.sharding import shard_multiple_shape
-from cruise_control_tpu.parallel.mesh import MeshEngine, grid_mesh, shard_map_compat
+from cruise_control_tpu.parallel.mesh import MeshEngine, grid_mesh, shard_map_unchecked
 from cruise_control_tpu.parallel.model_shard import stable_grouped_order
 from cruise_control_tpu.testing.fixtures import RandomClusterSpec, random_cluster_fast
 
@@ -109,6 +109,30 @@ def test_sharded_mode_gate():
     ).model_sharded
 
 
+def test_sharded_model_leaves_no_whole_copy_on_one_device():
+    """The engine builds its statics whole on the default device; once
+    they are placed in slices, no device may keep that whole copy beside
+    its slice (the 1.09 GB vs 83 MB per-device asymmetry of the CPU
+    mesh bench at 25k brokers / 2M partitions)."""
+    import gc
+
+    from cruise_control_tpu.common.profiling import per_device_live_bytes
+
+    state = jax.device_get(_small_state())  # host-resident input
+    model_bytes = sum(leaf.nbytes for leaf in jax.tree.leaves(state))
+    gc.collect()
+    before = per_device_live_bytes()
+    me = MeshEngine(
+        state, DEFAULT_CHAIN, mesh=grid_mesh(1, N), config=CFG,
+        model_shard_min_partitions=1,
+    )
+    gc.collect()
+    after = per_device_live_bytes()
+    held = [after.get(d.id, 0.0) - before.get(d.id, 0.0) for d in jax.devices()[:N]]
+    assert max(held) - min(held) < model_bytes / 2, held
+    assert me.engine.statics is me.statics
+
+
 def test_psum_segment_sum_exactness():
     """Shard-local segment_sum + psum == single-device segment_sum, bit
     for bit, on integer-quantized f32 loads — the identity every broker
@@ -128,7 +152,7 @@ def test_psum_segment_sum_exactness():
         return jax.lax.psum(part, "model")[None]
 
     out = jax.jit(
-        shard_map_compat(
+        shard_map_unchecked(
             f, mesh, in_specs=(P("model"), P("model")), out_specs=P("model")
         )
     )(vals, seg)
@@ -164,7 +188,7 @@ def test_variadic_sort_miscompile_guard():
 
     On the pinned jax/XLA build, a VARIADIC (two-operand) lax.sort of
     shard-varying data — jnp.argsort lowers to one — inside a
-    shard_map(check_rep=False) program whose result rides a lax.scan ys
+    shard_map(check_vma=False) program whose result rides a lax.scan ys
     export silently hands every device device 0's sort output, corrupting
     even the scan carry.  The packed SINGLE-operand sort must stay
     correct under the exact graph shape that triggers the miscompile; if
@@ -192,7 +216,7 @@ def test_variadic_sort_miscompile_guard():
         )
 
     acc, o, xs = jax.jit(
-        shard_map_compat(
+        shard_map_unchecked(
             fn, mesh, in_specs=(P("m"),), out_specs=(P("r"), P("r"), P("r"))
         )
     )(x)

@@ -1,5 +1,5 @@
-"""Shipped operator sample configs must actually work (VERDICT r4 missing
-#4: the reference ships config/cruisecontrol.properties +
+"""Shipped operator sample configs must actually work (the reference
+ships config/cruisecontrol.properties +
 capacity*.json; an operator must not have to author them from scratch).
 
 Reference analogs: config/cruisecontrol.properties:1, capacity.json,

@@ -284,6 +284,30 @@ def test_batched_matches_sequential_objectives():
         assert np.array_equal(np.asarray(v, np.float64), viol[i])
 
 
+def test_objective_f64_resolves_soft_goals_under_a_hard_violation():
+    """One hard goal 12.5% violated puts the objective near 625, where the
+    f32 ulp (6.1e-5) exceeds the lowest goals' weights: a change in those
+    goals vanishes from an f32 sum but not from the host f64 one, which
+    otherwise agrees with `evaluate`."""
+    chain = DEFAULT_CHAIN
+    names = chain.names()
+    v = np.zeros(len(names))
+    v[names.index("RackAwareGoal")] = 0.125
+    v[names.index("DiskUsageDistributionGoal")] = 0.075
+    worse = v.copy()
+    worse[names.index("LeaderBytesInDistributionGoal")] += 0.1
+    scores = np.zeros(len(names))
+    w32 = np.asarray(chain.weights, np.float32)
+    assert np.float32((w32 * v.astype(np.float32)).sum()) == np.float32(
+        (w32 * worse.astype(np.float32)).sum()
+    )
+    assert chain.objective_f64(worse, scores) > chain.objective_f64(v, scores)
+
+    state, _ = _catalogued_cluster()
+    obj, viol, sc = chain.evaluate(state)
+    assert np.isclose(chain.objective_f64(viol, sc), float(obj), rtol=1e-6)
+
+
 def test_evaluate_reuses_one_engine_across_batch():
     """The optimize pass over a scenario batch must compile ONE engine and
     rebind it for every other scenario (analyzer.engine-cache-* counters —

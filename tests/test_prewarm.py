@@ -352,6 +352,93 @@ def test_scan_and_boot_report_under_concurrent_writer(tmp_path):
         t.join(timeout=5)
 
 
+@pytest.mark.parametrize("env_set", [True, False])
+def test_compile_cache_placement(monkeypatch, tmp_path, env_set):
+    """JAX_COMPILATION_CACHE_DIR, when set, is THE cache directory: the
+    code sets no other (JAX reads the variable itself).  Without it the
+    cache sits at one fixed path inside the checkout."""
+    import jax
+
+    from cruise_control_tpu.config.app_config import CruiseControlConfig
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert compilation_cache.DEFAULT_CACHE_DIR == os.path.join(repo, ".jax_cache")
+    env_dir = str(tmp_path / "from-env")
+    if env_set:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env_dir)
+    else:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    monkeypatch.setattr(compilation_cache, "_enabled", False)
+    monkeypatch.setattr(compilation_cache, "_cache_dir", None)
+    monkeypatch.setattr(compilation_cache, "_boot_entries", None)
+    updates = []
+    monkeypatch.setattr(jax.config, "update", lambda k, v: updates.append((k, v)))
+
+    configured = str(tmp_path / "configured")
+    expected = env_dir if env_set else configured
+    assert compilation_cache.enable_persistent_cache(configured) == expected
+    dirs = [v for k, v in updates if k == "jax_compilation_cache_dir"]
+    assert dirs == ([] if env_set else [configured])
+
+    cfg = CruiseControlConfig({})
+    want = env_dir if env_set else compilation_cache.DEFAULT_CACHE_DIR
+    assert cfg.compile_cache_dir() == want
+    assert cfg.prewarm_manifest_dir() == os.path.join(want, "prewarm")
+    disabled = CruiseControlConfig({"tpu.compile.cache.dir": ""}).compile_cache_dir()
+    assert disabled == (env_dir if env_set else None)
+
+
+def test_exit_waits_for_in_flight_warm_compile(tmp_path):
+    """A process that exits while the warm pool compiles must exit 0,
+    not abort in interpreter finalization."""
+    import subprocess
+    import sys
+    import textwrap
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    child = textwrap.dedent(f"""
+        import sys, threading
+        sys.path.insert(0, {repo!r})
+        import jax, jax.numpy as jnp
+        from cruise_control_tpu.analyzer.engine import warm_pool_submit
+
+        def big(x):
+            for _ in range(300):
+                x = jnp.sin(x) @ x + jnp.cos(x).T
+            return x
+
+        started = threading.Event()
+
+        def compile_it():
+            started.set()
+            av = jax.ShapeDtypeStruct((64, 64), jnp.float32)
+            return jax.jit(big).trace(av).lower().compile()
+
+        fut = warm_pool_submit(compile_it)
+        warm_pool_submit(compile_it, priority=10)  # queued: dropped at exit
+        started.wait(30)
+        print("exiting mid-compile", flush=True)
+    """)
+    env = dict(os.environ, JAX_PLATFORMS="cpu", JAX_COMPILATION_CACHE_DIR="")
+    out = subprocess.run(
+        [sys.executable, "-c", child], env=env, capture_output=True,
+        text=True, timeout=300,
+    )
+    assert out.returncode == 0, out.stderr[-2000:]
+    assert "exiting mid-compile" in out.stdout
+
+
+def test_warm_pool_wait_idle():
+    pool = _WarmPool()
+    pool.ensure_workers(1)
+    release = threading.Event()
+    pool.submit(lambda: release.wait(10))
+    pool.submit(lambda: None, priority=5)
+    assert not pool.wait_idle(0.05)
+    release.set()
+    assert pool.wait_idle(10)
+
+
 # ------------------------------------------------------ warm-pool priority
 
 
