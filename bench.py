@@ -1211,64 +1211,10 @@ def mesh(smoke_mode: bool) -> int:
     return 0 if ok else 1
 
 
-def trace_overhead() -> int:
-    """`bench.py --trace-overhead`: tracing is ON by default on the hot
-    proposal path, so its cost is gated, not assumed.  Runs the smoke
-    workload with the flight recorder enabled vs disabled (same compiled
-    engine, min-of-N walls) and fails when tracing adds more than 2%.
-    A small absolute epsilon keeps sub-millisecond CPU timing noise from
-    failing runs whose spans cost nothing."""
-    import jax
-
-    from cruise_control_tpu.analyzer import GoalOptimizer, OptimizerConfig
-    from cruise_control_tpu.common.trace import Tracer
-    from cruise_control_tpu.testing.fixtures import RandomClusterSpec, random_cluster_fast
-
-    state = random_cluster_fast(
-        RandomClusterSpec(
-            num_brokers=24, num_partitions=1500, num_racks=6, num_topics=12, skew=1.0
-        ),
-        seed=7,
-    )
-    cfg = OptimizerConfig(
-        num_candidates=512, leadership_candidates=128, swap_candidates=64,
-        steps_per_round=16, num_rounds=4, init_temperature_scale=0.0, seed=0,
-    )
-    reps = 7
-    walls: dict[str, float] = {}
-    n_spans = 0
-    for mode in ("traced", "untraced"):
-        tracer = Tracer(enabled=(mode == "traced"))
-        opt = GoalOptimizer(config=cfg, tracer=tracer)
-        opt.optimize(state)  # warm: compile outside the measurement
-        best = float("inf")
-        for _ in range(reps):
-            t0 = time.monotonic()
-            opt.optimize(state)
-            best = min(best, time.monotonic() - t0)
-        walls[mode] = best
-        if mode == "traced":
-            n_spans = len(tracer._all_spans())
-    overhead = walls["traced"] / max(walls["untraced"], 1e-9) - 1.0
-    ok = walls["traced"] <= walls["untraced"] * 1.02 + 0.002
-    _emit(
-        metric="trace_overhead_smoke",
-        value=round(walls["traced"], 4),
-        unit="s",
-        vs_baseline=round(overhead, 4),
-        traced_wall_s=round(walls["traced"], 4),
-        untraced_wall_s=round(walls["untraced"], 4),
-        overhead_pct=round(overhead * 100, 2),
-        spans_recorded=n_spans,
-        ok=ok,
-    )
-    return 0 if ok else 1
-
-
 def blackbox_overhead() -> int:
     """`bench.py --blackbox-overhead`: the black-box dispatch spool is ON
     by default wherever a durable directory exists, so its cost is gated
-    by measurement, not assumption — same shape as --trace-overhead.
+    by measurement, not assumption.
 
     Runs the smoke workload with the recorder spooling to a temp
     directory vs disabled (same compiled engine, min-of-N walls) and
@@ -2464,8 +2410,6 @@ def main():
         sys.exit(mesh_chaos("--smoke" in sys.argv))
     if "--mesh" in sys.argv:
         sys.exit(mesh("--smoke" in sys.argv))
-    if "--trace-overhead" in sys.argv:
-        sys.exit(trace_overhead())
     if "--blackbox-overhead" in sys.argv:
         sys.exit(blackbox_overhead())
     if "--ledger-overhead" in sys.argv:

@@ -71,6 +71,7 @@ from __future__ import annotations
 import contextlib
 import contextvars
 import dataclasses
+import functools
 import logging
 import threading
 import time
@@ -613,6 +614,12 @@ FULL_CHECK_BUDGET = 2
 SEGMENT_MAX_ROUNDS = 64
 
 
+def rounds_ran(ran) -> int:
+    """Anneal rounds a run executed, from its fetched per-round `ran`
+    flags (the timing record's `rounds`)."""
+    return int(np.asarray(ran).sum())
+
+
 def snapshot_host_tree(tree):
     """Device->host fetch that OWNS its memory.  `jax.device_get` alone is
     not a snapshot: on the CPU backend it returns zero-copy numpy views of
@@ -1006,6 +1013,53 @@ def _uniform_idx(key: jax.Array, shape, n: jax.Array) -> jax.Array:
     return jnp.minimum((u * n.astype(jnp.float32)).astype(jnp.int32), n - 1)
 
 
+#: The anneal's phases, as `jax.named_scope`s on the traced code: every
+#: op of an engine program carries the innermost phase it was traced
+#: under in its name stack (a profiler trace's `tf_op`), so a device trace
+#: splits the anneal's time by phase.  A phase nests inside another (the
+#: candidate gathers of `anneal_sample` inside a move kind's
+#: `anneal_score`) except under the two that own their whole subtree:
+#: refresh and eval recompute aggregates, globals and goal terms with the
+#: helpers the step's phases use.  Scopes change op metadata only.
+ANNEAL_PHASES = (
+    "anneal_sample", "anneal_score", "anneal_accept", "anneal_refresh",
+    "anneal_eval",
+)
+_OWNING_PHASES = frozenset({"anneal_refresh", "anneal_eval"})
+#: the phase open on this thread while a program traces (warm-pool
+#: workers trace concurrently)
+_TRACING_PHASE = threading.local()
+
+
+@contextlib.contextmanager
+def anneal_phase(name: str):
+    """Trace the enclosed code under the phase scope `name`."""
+    outer = getattr(_TRACING_PHASE, "name", None)
+    if outer == name or outer in _OWNING_PHASES:
+        yield
+        return
+    _TRACING_PHASE.name = name
+    try:
+        with jax.named_scope(name):
+            yield
+    finally:
+        _TRACING_PHASE.name = outer
+
+
+def in_phase(name: str):
+    """Decorator: the function traces under `anneal_phase(name)`."""
+
+    def deco(fn):
+        @functools.wraps(fn)
+        def traced(*args, **kwargs):
+            with anneal_phase(name):
+                return fn(*args, **kwargs)
+
+        return traced
+
+    return deco
+
+
 class Engine:
     """Compiled optimization engine bound to one ClusterShape.
 
@@ -1254,13 +1308,18 @@ class Engine:
                     self._jit_run_fused.trace(sx_av, carry_av).lower().compile()
                 )
 
-                def flat(*leaves):
+                # named like the program it flattens: the exported
+                # module's ops keep `jit(_run_fused_impl)` in their name
+                # stack, beside the anneal's phase scopes
+                def _run_fused_impl(*leaves):
                     sx, carry = jax.tree.unflatten(in_def, list(leaves))
                     return tuple(jax.tree.leaves(self._run_fused_impl(sx, carry)))
 
                 # persist + compile the exported twin off this (waited-on)
                 # path: strictly lower priority than every pending compile
-                aot.save_async(flat, leaves_av, donate, priority=priority + 1_000)
+                aot.save_async(
+                    _run_fused_impl, leaves_av, donate, priority=priority + 1_000
+                )
                 return result
             self._record_fused_trace("fresh")
             return self._jit_run_fused.trace(sx_av, carry_av).lower().compile()
@@ -1550,6 +1609,7 @@ class Engine:
         aggregates, so no O(R) segment-sums are recomputed."""
         return self._eval_impl(sx, carry)[1]
 
+    @in_phase("anneal_eval")
     def _eval_impl(self, sx: EngineStatics, carry: EngineCarry):
         """(full objective, worst per-goal violation) as ONE program.
 
@@ -1560,6 +1620,7 @@ class Engine:
         obj, viol = self._eval_vec_impl(sx, carry)
         return obj, jnp.max(viol)
 
+    @in_phase("anneal_eval")
     def _eval_vec_impl(self, sx: EngineStatics, carry: EngineCarry):
         """(full objective, per-goal violation VECTOR f32[G]) from the
         carry's incremental aggregates — the convergence-diagnostics
@@ -1659,6 +1720,7 @@ class Engine:
     # objective terms
     # ------------------------------------------------------------------
 
+    @in_phase("anneal_score")
     def _globals(self, sx: EngineStatics, carry: EngineCarry):
         """Per-step frozen global scalars, O(B + T·B) from aggregates."""
         st = sx.state
@@ -1846,6 +1908,7 @@ class Engine:
     # candidate generation + delta evaluation
     # ------------------------------------------------------------------
 
+    @in_phase("anneal_sample")
     def _sample_sources(self, sx, key: jax.Array, n: int, plan) -> jax.Array:
         """n source replica ids; `importance_fraction` of them drawn by a
         two-stage plan draw (broker ~ categorical(objective contribution),
@@ -1868,6 +1931,7 @@ class Engine:
             r = jnp.concatenate([r, r_imp])
         return r
 
+    @in_phase("anneal_sample")
     def _sample_dests(
         self, sx, key: jax.Array, n: int, r: jax.Array, *, with_mask: bool = False
     ):
@@ -1908,6 +1972,7 @@ class Engine:
             return out, use
         return out
 
+    @in_phase("anneal_sample")
     def _slice_draws(self, slice_, *arrays):
         """Candidate-axis sharding (parallel/mesh.py): keep only one mesh
         shard's contiguous slice of the full-K draw vectors.
@@ -1970,15 +2035,18 @@ class Engine:
         kind, attr = self._ROW_SOURCES[field]
         return getattr(carry if kind == "carry" else sx.state, attr)
 
+    @in_phase("anneal_sample")
     def _take_rows(self, sx, carry, ids, fields):
         """{field: column[ids]} for (global) replica ids `ids`."""
         return {f: self._row_source(sx, carry, f)[ids] for f in fields}
 
+    @in_phase("anneal_sample")
     def _take_members(self, sx, part):
         """[K, max_rf] partition->replica member table rows at (global)
         partition ids (member entries are global replica ids; >= R pads)."""
         return sx.part_replicas[part]
 
+    @in_phase("anneal_sample")
     def _member_field(self, sx, carry, members, field, fill):
         """Per-member column gather with the table's >= R padding masked
         to `fill` (members carry global replica ids)."""
@@ -1990,24 +2058,26 @@ class Engine:
         """part_rack_count[(global) partition, rack] as f32."""
         return carry.part_rack_count[part, rack].astype(jnp.float32)
 
+    @in_phase("anneal_score")
     def _replica_candidates(
         self, sx, carry: EngineCarry, key: jax.Array, g, plan=None, slice_=None
     ):
         """K_r replica-move candidates -> (delta, src, dst, part, payload)."""
         st = sx.state
         K = self.K_r
-        k1, k2 = jax.random.split(key)
-        r = self._sample_sources(sx, k1, K, plan)
-        if self.config.diagnostics:
-            # same draws, plus the took-the-prior-branch mask so per-round
-            # prior usage can be counted — placements untouched
-            pos, from_prior = self._sample_dests(sx, k2, K, r, with_mask=True)
-            dst = sx.dest_ids[pos]
-            r, dst, from_prior = self._slice_draws(slice_, r, dst, from_prior)
-        else:
-            dst = sx.dest_ids[self._sample_dests(sx, k2, K, r)]
-            r, dst = self._slice_draws(slice_, r, dst)
-            from_prior = None
+        with anneal_phase("anneal_sample"):
+            k1, k2 = jax.random.split(key)
+            r = self._sample_sources(sx, k1, K, plan)
+            if self.config.diagnostics:
+                # same draws, plus the took-the-prior-branch mask so
+                # per-round prior usage can be counted — placements untouched
+                pos, from_prior = self._sample_dests(sx, k2, K, r, with_mask=True)
+                dst = sx.dest_ids[pos]
+                r, dst, from_prior = self._slice_draws(slice_, r, dst, from_prior)
+            else:
+                dst = sx.dest_ids[self._sample_dests(sx, k2, K, r)]
+                r, dst = self._slice_draws(slice_, r, dst)
+                from_prior = None
         fields = ["broker", "part", "disk", "topic", "valid", "is_lead",
                   "load_leader", "load_follower"]
         if self.w.pref_leader != 0.0:
@@ -2114,6 +2184,7 @@ class Engine:
             payload["from_prior"] = from_prior
         return delta, feasible, src, dst, part, payload
 
+    @in_phase("anneal_score")
     def _intra_disk_candidates(
         self, sx, carry: EngineCarry, key: jax.Array, g, plan=None, slice_=None
     ):
@@ -2196,6 +2267,7 @@ class Engine:
             payload["from_prior"] = jnp.zeros(r.shape, bool)
         return delta, feasible, b, b, part, payload
 
+    @in_phase("anneal_score")
     def _swap_candidates(
         self, sx, carry: EngineCarry, key: jax.Array, g, plan=None, slice_=None
     ):
@@ -2219,10 +2291,11 @@ class Engine:
                 d_r=zi, d_q=zi,
             )
             return z, zb, zi, zi, zi, zi, payload
-        k1, k2 = jax.random.split(key)
-        r = self._sample_sources(sx, k1, K, plan)
-        q = _uniform_idx(k2, (K,), sx.n_source)
-        r, q = self._slice_draws(slice_, r, q)
+        with anneal_phase("anneal_sample"):
+            k1, k2 = jax.random.split(key)
+            r = self._sample_sources(sx, k1, K, plan)
+            q = _uniform_idx(k2, (K,), sx.n_source)
+            r, q = self._slice_draws(slice_, r, q)
         # ONE row bundle for both draw lanes (gather of a concat == concat
         # of gathers): the model-sharded twin resolves it with a single
         # psum round instead of two
@@ -2384,6 +2457,7 @@ class Engine:
         )
         return delta, feasible, src, dst, part_r, part_q, payload
 
+    @in_phase("anneal_score")
     def _leadership_candidates(
         self, sx, carry: EngineCarry, key: jax.Array, g, plan=None, slice_=None
     ):
@@ -2398,7 +2472,8 @@ class Engine:
             zl = jnp.zeros((0, NUM_RESOURCES), jnp.float32)
             payload = dict(rf=zi, rt=zi, dl_f=zl, dl_t=zl, dlbin_src=z, dlbin_dst=z)
             return z, zb, zi, zi, zi, payload
-        rt = self._slice_draws(slice_, _uniform_idx(key, (K,), sx.n_source))
+        with anneal_phase("anneal_sample"):
+            rt = self._slice_draws(slice_, _uniform_idx(key, (K,), sx.n_source))
         fields = ["broker", "part", "disk", "valid", "is_lead",
                   "load_leader", "load_follower"]
         if self.w.pref_leader != 0.0:
@@ -2475,6 +2550,7 @@ class Engine:
                        dlbin_dst=dlbin)
         return delta, feasible, src, dst, part, payload
 
+    @in_phase("anneal_score")
     def _move_delta(
         self,
         sx,
@@ -2616,6 +2692,7 @@ class Engine:
             sx, carry, *self._propose_kinds(sx, carry, k_r, k_s, k_l, g, plan)
         )
 
+    @in_phase("anneal_score")
     def _assemble_prop(self, sx: EngineStatics, carry: EngineCarry, raw_r, raw_s, raw_l):
         """Concatenate per-kind bundles into the selection/apply bundle
         (shared verbatim by the plain step and the mesh step's post-gather
@@ -2700,11 +2777,13 @@ class Engine:
         )
 
     def _step(self, sx: EngineStatics, carry: EngineCarry, temperature, plan=None):
-        key, k_r, k_s, k_l, k_u = jax.random.split(carry.key, 5)
+        with anneal_phase("anneal_sample"):
+            key, k_r, k_s, k_l, k_u = jax.random.split(carry.key, 5)
         g = self._globals(sx, carry)
         prop = self._propose(sx, carry, k_r, k_s, k_l, g, plan)
         return self._accept_select_apply(sx, carry, prop, temperature, key, k_u)
 
+    @in_phase("anneal_accept")
     def _accept_select_apply(
         self, sx: EngineStatics, carry: EngineCarry, prop, temperature, key, k_u
     ):
@@ -2891,6 +2970,7 @@ class Engine:
             host_load=hl,
         )
 
+    @in_phase("anneal_refresh")
     def _round_prep_impl(self, sx: EngineStatics, carry: EngineCarry):
         """Between-rounds bookkeeping as ONE program: refresh aggregates
         (wash float drift), build the next round's sampling plan, and read
@@ -2967,6 +3047,7 @@ class Engine:
         (carry, *_), ys = jax.lax.scan(round_body, init, jnp.arange(total))
         return carry, ys
 
+    @in_phase("anneal_eval")
     def _schedule_init(self, sx: EngineStatics, carry: EngineCarry):
         """(t0, plan0) of a fresh anneal: the initial temperature scale and
         round-0 sampling plan.  Shared by the whole-anneal fused program
@@ -3251,6 +3332,7 @@ class Engine:
             segments=len(ys_parts), blocking_syncs=len(ys_parts),
             device_s=round(device_s, 6),
             host_dispatch_s=round(time.monotonic() - t_start - device_s, 6),
+            rounds=rounds_ran(ys["ran"]),
         )
         conv = self._convergence_summary(ys)
         if conv is not None:
@@ -3420,6 +3502,7 @@ class Engine:
             timing=True, fused=True, blocking_syncs=1,
             host_dispatch_s=round(t_disp - t_start, 6),
             device_s=round(t_sync - t_disp, 6),
+            rounds=rounds_ran(ys["ran"]),
         )
         conv = self._convergence_summary(ys)
         if conv is not None:
@@ -3560,6 +3643,7 @@ class Engine:
             scatter_width=width,
             host_dispatch_s=round(t_disp - t_start, 6),
             device_s=round(t_sync - t_disp, 6),
+            rounds=rounds_ran(host["ys"]["ran"]),
         )
         conv = self._convergence_summary(host["ys"])
         if conv is not None:

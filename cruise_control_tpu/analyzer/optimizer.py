@@ -330,11 +330,14 @@ class GoalOptimizer:
         # one persistent jitted program for objective+violations+stats:
         # eager per-op dispatch on large models costs orders of magnitude
         # more than the computation itself
-        self._report = jax.jit(
-            lambda s: (
-                self.chain.evaluate(s, constraint=self.constraint)[:2],
-                compute_stats(s),
-            )
+        self._report = jax.jit(self._report_impl)
+
+    def _report_impl(self, state: ClusterState):
+        """((objective, per-goal violations), stats) of `state` as one
+        program: `jit__report_impl` in a device trace."""
+        return (
+            self.chain.evaluate(state, constraint=self.constraint)[:2],
+            compute_stats(state),
         )
 
     # ------------------------------------------------------------------
@@ -754,6 +757,8 @@ class GoalOptimizer:
                         "device_s", "blocking_syncs", "host_extract_s",
                         "engine_cache_hit", "engine_build_s", "bucket",
                         "mesh_shape", "collective_bytes",
+                        # anneal rounds run (early stop, extra rounds)
+                        "rounds",
                         # segmented (preemptible) execution under the
                         # device scheduler: how many wall-bounded slices
                         # this anneal dispatched as
@@ -1070,12 +1075,13 @@ class GoalOptimizer:
                 # metadata is reflatten-invariant; loads are scattered
                 # in-graph)
                 engine.rebind_prior(prior)
-                out_ll, out_fl, host, history = engine.run_cycle(
-                    state.replica_load_leader,
-                    state.replica_load_follower,
-                    rows, leader_loads, follower_loads,
-                    initial_placement,
-                )
+                with self.tracer.span("engine.run", component="analyzer"):
+                    out_ll, out_fl, host, history = engine.run_cycle(
+                        state.replica_load_leader,
+                        state.replica_load_follower,
+                        rows, leader_loads, follower_loads,
+                        initial_placement,
+                    )
             finally:
                 self._unpin(engine)
             self._record(True)
@@ -1099,17 +1105,20 @@ class GoalOptimizer:
                 replica_offline=host["replica_offline"],
             )
             t_extract = time.monotonic()
-            if before_host is not None:
-                before_host = dict(
-                    before_host, replica_disk_bytes=host["replica_disk_bytes"]
-                )
-            else:
-                from cruise_control_tpu.analyzer.proposals import fetch_before_host
+            with self.tracer.span("analyzer.extract", component="analyzer"):
+                if before_host is not None:
+                    before_host = dict(
+                        before_host, replica_disk_bytes=host["replica_disk_bytes"]
+                    )
+                else:
+                    from cruise_control_tpu.analyzer.proposals import (
+                        fetch_before_host,
+                    )
 
-                before_host = fetch_before_host(state_before)
-            proposals = extract_proposals(
-                state_before, state_after, before_host=before_host
-            )
+                    before_host = fetch_before_host(state_before)
+                proposals = extract_proposals(
+                    state_before, state_after, before_host=before_host
+                )
             timing = next((h for h in history if h.get("timing")), None)
             if timing is None:
                 timing = dict(timing=True)
@@ -1160,7 +1169,7 @@ class GoalOptimizer:
                     k: timing.get(k)
                     for k in (
                         "device_s", "blocking_syncs", "host_extract_s",
-                        "scatter_width", "bucket", "convergence",
+                        "scatter_width", "bucket", "convergence", "rounds",
                     )
                     if timing.get(k) is not None
                 },
@@ -1262,13 +1271,19 @@ class GoalOptimizer:
 
         t0 = time.monotonic()
         cfg = config or self.config
+        # the device body's stages, each a span beneath `device.optimize`
+        # so a trace names what the host did while the chip idled
+        def stage(name):
+            return self.tracer.span(name, component="analyzer")
+
         # input sanity first — a rejected state must not trigger engine
         # construction or background compilation.  The ON-DEVICE check
         # transfers a [5] count vector instead of the model's bulk arrays
         # (the bulk transfer costs more than the checks); the host
         # validator re-runs for the detailed message only on failure
-        count_dispatch("analyzer.validate")
-        input_checks = np.asarray(validate_on_device(state))
+        with stage("analyzer.validate"):
+            count_dispatch("analyzer.validate")
+            input_checks = np.asarray(validate_on_device(state))
         if input_checks.any():
             validate(state)  # raises with per-invariant detail
             bad = [n for n, c in zip(DEVICE_CHECKS, input_checks) if c]
@@ -1281,19 +1296,21 @@ class GoalOptimizer:
         cache_info = None
         single = self.parallel_mode == "single" or force_single
         try:
-            if single:
-                engine, cache_info = self._engine_for(
-                    state, options, cfg, prior=prior
-                )
-            else:
-                if initial_placement is not None or prior is not None:
-                    raise ValueError(
-                        "warm-start placement / move-acceptance prior are "
-                        f"single-device only (tpu.parallel.mode={self.parallel_mode!r})"
+            with stage("analyzer.engine"):
+                if single:
+                    engine, cache_info = self._engine_for(
+                        state, options, cfg, prior=prior
                     )
-                engine, cache_info = self._parallel_engine(
-                    state, options, cfg, devices=devices
-                )
+                else:
+                    if initial_placement is not None or prior is not None:
+                        raise ValueError(
+                            "warm-start placement / move-acceptance prior are "
+                            "single-device only "
+                            f"(tpu.parallel.mode={self.parallel_mode!r})"
+                        )
+                    engine, cache_info = self._parallel_engine(
+                        state, options, cfg, devices=devices
+                    )
             # only at production scale: tiny test engines compile in
             # hundreds of ms, and eagerly tracing the rarely-used
             # programs (full-chain violations) would cost more than
@@ -1316,8 +1333,9 @@ class GoalOptimizer:
                 )
             ):
                 engine.precompile_async()
-            count_dispatch("analyzer.report")
-            (obj_b, viol_b), stats_b = self._report(state)
+            with stage("analyzer.report"):
+                count_dispatch("analyzer.report")
+                (obj_b, viol_b), stats_b = self._report(state)
             # the proposal diff needs bulk BEFORE-state arrays on host;
             # pull them on a side thread while the device anneals — input
             # buffers are immutable, and the copy rides the link during
@@ -1342,9 +1360,11 @@ class GoalOptimizer:
                         resume = None
                     else:
                         run_kwargs["resume"] = resume
-                with profiler_trace(self.profiler_dir):
+                with profiler_trace(self.profiler_dir), stage("engine.run"):
                     final, history = engine.run(verbose=verbose, **run_kwargs)
-                before_host = before_host_f.result()
+                # the proposal diff's input, if its copy is still in flight
+                with stage("analyzer.extract"):
+                    before_host = before_host_f.result()
         finally:
             # run() is done with the engine's buffers (everything below
             # reads only the run's OUTPUT arrays); release the eviction
@@ -1354,13 +1374,16 @@ class GoalOptimizer:
                 self._unpin(engine)
         # dispatch the result report + the on-device sanity check, then do
         # the host-side proposal diff while the device drains them
-        count_dispatch("analyzer.report")
-        (obj_a, viol_a), stats_a = self._report(final)
-        count_dispatch("analyzer.validate")
-        final_checks = validate_on_device(final)
-        t_extract = time.monotonic()
-        proposals = extract_proposals(state, final, before_host=before_host)
-        extract_s = time.monotonic() - t_extract
+        with stage("analyzer.report"):
+            count_dispatch("analyzer.report")
+            (obj_a, viol_a), stats_a = self._report(final)
+        with stage("analyzer.check"):
+            count_dispatch("analyzer.validate")
+            final_checks = validate_on_device(final)
+        with stage("analyzer.extract"):
+            t_extract = time.monotonic()
+            proposals = extract_proposals(state, final, before_host=before_host)
+            extract_s = time.monotonic() - t_extract
         # complete the device/host timing split the engine started: the
         # proposal diff is the optimizer's host-side share of the wall
         # clock, overlapping the device draining the report programs above
@@ -1397,14 +1420,16 @@ class GoalOptimizer:
                 self.sensors.gauge("analyzer.mesh-model-psum-bytes").set(
                     int(timing.get("model_psum_bytes") or 0)
                 )
-        final_checks = np.asarray(final_checks)
+        # the sync of the report and check dispatched above
+        with stage("analyzer.check"):
+            final_checks = np.asarray(final_checks)
+            viol_b = np.asarray(viol_b)
+            viol_a = np.asarray(viol_a)
         if final_checks.any():
             bad = [n for n, c in zip(DEVICE_CHECKS, final_checks) if c]
             # re-run the host validator for the detailed message
             validate(final)
             raise ValueError(f"optimized state failed sanity checks: {bad}")
-        viol_b = np.asarray(viol_b)
-        viol_a = np.asarray(viol_a)
         wall = time.monotonic() - t0
         if cache_info is not None and not cache_info.get("engine_cache_hit", True):
             # cold run: the whole wall (incl. the lazy XLA compile) bills
@@ -1490,12 +1515,7 @@ class GoalOptimizer:
         )
         final = jax.tree.map(np.asarray, final)
         if self._report_cpu is None:
-            self._report_cpu = jax.jit(
-                lambda s: (
-                    self.chain.evaluate(s, constraint=self.constraint)[:2],
-                    compute_stats(s),
-                )
-            )
+            self._report_cpu = jax.jit(self._report_impl)
         with jax.default_device(cpu):
             (obj_b, viol_b), stats_b = self._report_cpu(host_state)
             (obj_a, viol_a), stats_a = self._report_cpu(final)

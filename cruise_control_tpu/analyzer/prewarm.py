@@ -192,6 +192,19 @@ def _atomic_write(path: str, data: bytes) -> None:
         raise
 
 
+def exported_program(ex, donate_argnums):
+    """The jitted program that runs a deserialized fused-anneal export.
+    Named like the fresh program, so a device trace shows one
+    `jit__run_fused_impl` whichever tier served it; load and save build
+    the same module, so the save's compile seeds the load's cache entry."""
+    import jax
+
+    def _run_fused_impl(*leaves):
+        return ex.call(*leaves)
+
+    return jax.jit(_run_fused_impl, donate_argnums=tuple(donate_argnums))
+
+
 class _AotHandle:
     """Load/save seam for ONE fused program's AOT artifact.
 
@@ -251,7 +264,7 @@ class _AotHandle:
 
             ex = jax_export.deserialize(payload)
             compiled = (
-                jax.jit(ex.call, donate_argnums=tuple(donate_argnums))
+                exported_program(ex, donate_argnums)
                 .trace(*leaves_avals)
                 .lower()
                 .compile()
@@ -316,7 +329,7 @@ class _AotHandle:
         # disk hit, not a fresh compile.  (The exported module is not
         # byte-identical to the plain jit's, so without this the first
         # AOT boot would pay the compile the cache was supposed to skip.)
-        jax.jit(ex.call, donate_argnums=tuple(donate_argnums)).trace(
+        exported_program(ex, donate_argnums).trace(
             *leaves_avals
         ).lower().compile()
         self.store._count("analyzer.prewarm-aot-exports")

@@ -146,9 +146,15 @@ def profiler_trace(dump_dir: str | None):
     if not dump_dir:
         yield
         return
+    import os
+
     import jax
 
     try:
+        # a directory the dump cannot be written to fails here, before a
+        # profiler session starts: a session whose dump failed would stay
+        # open and refuse every later trace in the process
+        os.makedirs(dump_dir, exist_ok=True)
         ctx = jax.profiler.trace(dump_dir)
         ctx.__enter__()
     except Exception:  # noqa: BLE001 — profiling is best-effort

@@ -211,7 +211,8 @@ class LoadMonitor:
         with sensors.timer("monitor.cluster-model-creation-timer").time():
             with tracer.span("monitor.cluster_model", component="monitor") as sp:
                 state = self._cluster_model_impl(
-                    requirements, allow_capacity_estimation=allow_capacity_estimation
+                    requirements, tracer,
+                    allow_capacity_estimation=allow_capacity_estimation,
                 )
                 s = state.shape
                 sp.set(
@@ -223,25 +224,30 @@ class LoadMonitor:
     def _cluster_model_impl(
         self,
         requirements: ModelCompletenessRequirements,
+        tracer,
         *,
         allow_capacity_estimation: bool = True,
     ) -> ClusterState:
-        topology = self.metadata.refresh()
-        if self.topic_filter is not None:
-            import dataclasses as _dc
+        # the build's three stages, each a span beneath
+        # `monitor.cluster_model`
+        with tracer.span("monitor.topology", component="monitor"):
+            topology = self.metadata.refresh()
+            if self.topic_filter is not None:
+                import dataclasses as _dc
 
-            topology = _dc.replace(
-                topology,
-                partitions=tuple(
-                    p for p in topology.partitions if self.topic_filter(p.topic)
-                ),
+                topology = _dc.replace(
+                    topology,
+                    partitions=tuple(
+                        p for p in topology.partitions if self.topic_filter(p.topic)
+                    ),
+                )
+        with tracer.span("monitor.aggregate", component="monitor"):
+            agg = self.partition_aggregator.aggregate(
+                AggregationOptions(
+                    min_valid_entity_ratio=requirements.min_monitored_partitions_percentage,
+                    max_allowed_extrapolations_per_entity=self.max_allowed_extrapolations,
+                )
             )
-        agg = self.partition_aggregator.aggregate(
-            AggregationOptions(
-                min_valid_entity_ratio=requirements.min_monitored_partitions_percentage,
-                max_allowed_extrapolations_per_entity=self.max_allowed_extrapolations,
-            )
-        )
         if agg.completeness.valid_windows.size < requirements.min_required_num_windows:
             raise NotEnoughValidWindowsError(
                 f"{agg.completeness.valid_windows.size} valid windows < "
@@ -255,9 +261,10 @@ class LoadMonitor:
                 f"valid partition ratio {agg.completeness.valid_entity_ratio:.3f} < "
                 f"required {requirements.min_monitored_partitions_percentage:.3f}"
             )
-        state = self._build_state(
-            topology, agg, allow_capacity_estimation=allow_capacity_estimation
-        )
+        with tracer.span("monitor.build_state", component="monitor"):
+            state = self._build_state(
+                topology, agg, allow_capacity_estimation=allow_capacity_estimation
+            )
         with self._generation_lock:
             self._load_generation = agg.completeness.generation
         return state

@@ -83,7 +83,9 @@ from cruise_control_tpu.analyzer.engine import (
     SEGMENT_MAX_ROUNDS,
     SegmentContext,
     _WarmedFn,
+    anneal_phase,
     current_segment_context,
+    rounds_ran,
     snapshot_host_tree,
     start_warm_pool,
 )
@@ -190,7 +192,8 @@ class _ShardStepEngine(Engine):
             # identity slice, no collective: the traced program IS the
             # plain engine's step (the n=1 overhead guarantee)
             return Engine._step(self, sx, carry, temperature, plan)
-        key, k_r, k_s, k_l, k_u = jax.random.split(carry.key, 5)
+        with anneal_phase("anneal_sample"):
+            key, k_r, k_s, k_l, k_u = jax.random.split(carry.key, 5)
         g = self._globals(sx, carry)
         idx = jax.lax.axis_index(MODEL_AXIS)
         raw_r, raw_s, raw_l = self._propose_kinds(
@@ -642,6 +645,7 @@ class MeshEngine:
             device_s=round(t_sync - t_disp, 6),
             mesh_shape=[self.n_restarts, self.n],
             collective_bytes=self.collective_bytes_per_round,
+            rounds=rounds_ran(np.asarray(ys["ran"])[winner]),
         )
         if self.model_sharded:
             # only present when sharded — replicated-mode history records
@@ -872,6 +876,7 @@ class MeshEngine:
             ),
             mesh_shape=[self.n_restarts, self.n],
             collective_bytes=self.collective_bytes_per_round,
+            rounds=rounds_ran(np.asarray(ys["ran"])[winner]),
         )
         if resume is not None:
             timing["resumed_from_round"] = int(resume.base)

@@ -55,7 +55,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from cruise_control_tpu.analyzer.engine import Engine, _uniform_idx
+from cruise_control_tpu.analyzer.engine import Engine, _uniform_idx, in_phase
 
 MODEL_AXIS = "model"
 
@@ -219,15 +219,18 @@ class _ModelShardEngine(Engine):
 
     # ---- row-provider seam overrides (see Engine for the contracts) ----
 
+    @in_phase("anneal_sample")
     def _take_rows(self, sx, carry, ids, fields):
         cols = {f: self._row_source(sx, carry, f) for f in fields}
         return self._own_take(cols, ids, self._r_local)
 
+    @in_phase("anneal_sample")
     def _take_members(self, sx, part):
         return self._own_take(
             {"m": sx.part_replicas}, part, self._p_local
         )["m"]
 
+    @in_phase("anneal_sample")
     def _member_field(self, sx, carry, members, field, fill):
         src = {field: self._row_source(sx, carry, field)}
         vals = self._own_take(
@@ -282,6 +285,7 @@ class _ModelShardEngine(Engine):
             lead_cost=self.config.leadership_move_cost * unit,
         )
 
+    @in_phase("anneal_sample")
     def _sample_sources(self, sx, key, n, plan):
         """Replicated draws, ownership-resolved plan lookups.
 

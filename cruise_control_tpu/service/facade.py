@@ -1166,8 +1166,10 @@ class CruiseControl:
         if options is None:
             # config-level always-excluded topics apply to the default path
             # too (reference AnalyzerConfig
-            # topics.excluded.from.partition.movement)
-            options = self._build_options(state)
+            # topics.excluded.from.partition.movement); a span of its own,
+            # as it reads the model's broker mask back from the device
+            with self.tracer.span("analyzer.options", component="analyzer"):
+                options = self._build_options(state)
         optimizer = self.optimizer if goals is None else self._make_optimizer(goals)
         progress.add_step(BatchedOptimization(optimizer.config.num_rounds))
         # reference GoalOptimizer proposal-computation-timer (:116,155);

@@ -25,6 +25,7 @@ from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 
 import numpy as np
 
+from cruise_control_tpu.common.trace import NOOP_SPAN
 from cruise_control_tpu.common.resources import RESOURCE_NAMES, Resource
 from cruise_control_tpu.service.facade import CruiseControl
 from cruise_control_tpu.service.parameters import ParameterError, build_override_maps
@@ -509,6 +510,10 @@ class CruiseControlApp:
         # whole operation files under this cluster's trace components.
         tracer = getattr(cc, "tracer", None) or self.tracer
         trace_id = tracer.new_trace_id() if tracer.enabled else ""
+        # the operation's wait in the user-task pool: opened at submit on
+        # this thread, finished (and parented under the root) by the pool
+        # thread as the root opens
+        queued: list = []
 
         def wrapped(progress, _op=fn):
             self._local.cc = cc
@@ -517,7 +522,11 @@ class CruiseControlApp:
             with tracer.span(
                 f"service.{endpoint}", component="service",
                 trace_id=trace_id, root=True, **span_attrs,
-            ):
+            ) as root:
+                for q in queued:
+                    if q is not NOOP_SPAN:
+                        q.parent_id = root.span_id
+                    q.finish()
                 out = _op(progress)
             # degraded serving must be visible in the ops audit trail, not
             # only in the payload: the analyzer's device breaker is open
@@ -556,13 +565,19 @@ class CruiseControlApp:
                 self.tenant_max_pending
                 if self.fleet is not None and cluster_id else 0
             )
+            queued.append(tracer.start_span(
+                "tasks.queue", component="service", trace_id=trace_id,
+                root=True,
+            ))
             try:
                 return self.user_tasks.submit(
                     endpoint, fn, client_id=client, trace_id=trace_id,
                     cluster_id=cluster_id, cluster_max_active=cap,
                 )
-            except TenantOverloadError:
-                cc.sensors.counter("fleet.tenant-rejections").inc()
+            except BaseException as e:
+                queued.pop().finish(error=repr(e))
+                if isinstance(e, TenantOverloadError):
+                    cc.sensors.counter("fleet.tenant-rejections").inc()
                 raise
 
         key = getattr(self._local, "session_key", None)

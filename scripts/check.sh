@@ -229,12 +229,6 @@ finally:
 EOF
 metrics_rc=$?
 
-echo "== check.sh: trace overhead gate (tracing-on adds <2% to a smoke run) =="
-# named gate: the flight recorder is ON by default on the hot proposal
-# path, so its cost is pinned by measurement, not assumption
-JAX_PLATFORMS=cpu python bench.py --trace-overhead
-overhead_rc=$?
-
 echo "== check.sh: black-box overhead gate (spool-on adds <2%, disabled path writes nothing) =="
 # named gate: the crash-durable dispatch spool is ON by default wherever
 # a durable dir exists; its per-dispatch write+flush must stay
@@ -282,5 +276,5 @@ python -m pytest tests/test_trace.py -q
 trace_rc=$?
 
 echo
-echo "check.sh summary: suite=$suite_rc dryrun=$dryrun_rc entry=$entry_rc smoke=$smoke_rc mesh=$mesh_rc mesh_model=$mesh_model_rc churn=$churn_rc streaming=$streaming_rc controller=$controller_rc coldstart=$coldstart_rc prewarm=$prewarm_rc fleet_smoke=$fleet_smoke_rc fleet=$fleet_rc fleet_ha=$fleet_ha_rc ha_smoke=$ha_smoke_rc scheduler=$scheduler_rc scenarios=$scenarios_rc planner=$planner_rc faults=$faults_rc mesh_ft=$mesh_ft_rc mesh_chaos=$mesh_chaos_rc recovery=$recovery_rc metrics=$metrics_rc overhead=$overhead_rc blackbox_overhead=$blackbox_overhead_rc ledger_overhead=$ledger_overhead_rc ledger=$ledger_rc blackbox=$blackbox_rc slo=$slo_rc trace=$trace_rc"
-[ "$suite_rc" -eq 0 ] && [ "$dryrun_rc" -eq 0 ] && [ "$entry_rc" -eq 0 ] && [ "$smoke_rc" -eq 0 ] && [ "$mesh_rc" -eq 0 ] && [ "$mesh_model_rc" -eq 0 ] && [ "$churn_rc" -eq 0 ] && [ "$streaming_rc" -eq 0 ] && [ "$controller_rc" -eq 0 ] && [ "$coldstart_rc" -eq 0 ] && [ "$prewarm_rc" -eq 0 ] && [ "$fleet_smoke_rc" -eq 0 ] && [ "$fleet_rc" -eq 0 ] && [ "$fleet_ha_rc" -eq 0 ] && [ "$ha_smoke_rc" -eq 0 ] && [ "$scheduler_rc" -eq 0 ] && [ "$scenarios_rc" -eq 0 ] && [ "$planner_rc" -eq 0 ] && [ "$faults_rc" -eq 0 ] && [ "$mesh_ft_rc" -eq 0 ] && [ "$mesh_chaos_rc" -eq 0 ] && [ "$recovery_rc" -eq 0 ] && [ "$metrics_rc" -eq 0 ] && [ "$overhead_rc" -eq 0 ] && [ "$blackbox_overhead_rc" -eq 0 ] && [ "$ledger_overhead_rc" -eq 0 ] && [ "$ledger_rc" -eq 0 ] && [ "$blackbox_rc" -eq 0 ] && [ "$slo_rc" -eq 0 ] && [ "$trace_rc" -eq 0 ]
+echo "check.sh summary: suite=$suite_rc dryrun=$dryrun_rc entry=$entry_rc smoke=$smoke_rc mesh=$mesh_rc mesh_model=$mesh_model_rc churn=$churn_rc streaming=$streaming_rc controller=$controller_rc coldstart=$coldstart_rc prewarm=$prewarm_rc fleet_smoke=$fleet_smoke_rc fleet=$fleet_rc fleet_ha=$fleet_ha_rc ha_smoke=$ha_smoke_rc scheduler=$scheduler_rc scenarios=$scenarios_rc planner=$planner_rc faults=$faults_rc mesh_ft=$mesh_ft_rc mesh_chaos=$mesh_chaos_rc recovery=$recovery_rc metrics=$metrics_rc blackbox_overhead=$blackbox_overhead_rc ledger_overhead=$ledger_overhead_rc ledger=$ledger_rc blackbox=$blackbox_rc slo=$slo_rc trace=$trace_rc"
+[ "$suite_rc" -eq 0 ] && [ "$dryrun_rc" -eq 0 ] && [ "$entry_rc" -eq 0 ] && [ "$smoke_rc" -eq 0 ] && [ "$mesh_rc" -eq 0 ] && [ "$mesh_model_rc" -eq 0 ] && [ "$churn_rc" -eq 0 ] && [ "$streaming_rc" -eq 0 ] && [ "$controller_rc" -eq 0 ] && [ "$coldstart_rc" -eq 0 ] && [ "$prewarm_rc" -eq 0 ] && [ "$fleet_smoke_rc" -eq 0 ] && [ "$fleet_rc" -eq 0 ] && [ "$fleet_ha_rc" -eq 0 ] && [ "$ha_smoke_rc" -eq 0 ] && [ "$scheduler_rc" -eq 0 ] && [ "$scenarios_rc" -eq 0 ] && [ "$planner_rc" -eq 0 ] && [ "$faults_rc" -eq 0 ] && [ "$mesh_ft_rc" -eq 0 ] && [ "$mesh_chaos_rc" -eq 0 ] && [ "$recovery_rc" -eq 0 ] && [ "$metrics_rc" -eq 0 ] && [ "$blackbox_overhead_rc" -eq 0 ] && [ "$ledger_overhead_rc" -eq 0 ] && [ "$ledger_rc" -eq 0 ] && [ "$blackbox_rc" -eq 0 ] && [ "$slo_rc" -eq 0 ] && [ "$trace_rc" -eq 0 ]

@@ -219,6 +219,33 @@ def test_restart_loads_artifact_and_skips_tracing(tmp_path, state, golden_artifa
     ), "AOT path changed the result"
 
 
+def test_artifact_program_keeps_name_and_phase_scopes(tmp_path, state, golden_artifact):
+    """The AOT-served anneal runs as `jit__run_fused_impl`, and the
+    export/deserialize round trip keeps the anneal's phase scopes in its
+    ops' metadata (what a device trace's `tf_op` reads)."""
+    import jax
+    import jax.numpy as jnp
+
+    from cruise_control_tpu.analyzer.engine import ANNEAL_PHASES
+
+    _install_artifact(tmp_path, golden_artifact)
+    e = Engine(state, DEFAULT_CHAIN, config=CFG)
+    sx_av = e.statics_avals()
+    carry_av = jax.eval_shape(
+        e._init_impl, sx_av, jax.ShapeDtypeStruct((2,), jnp.uint32)
+    )
+    leaves_av, _, donate = e._fused_flat_inputs(sx_av, carry_av)
+    max_rf = int(e.statics.part_replicas.shape[1])
+    compiled = _store(tmp_path).aot_handle(state.shape, max_rf, CFG).load(
+        leaves_av, donate
+    )
+    assert compiled is not None
+    text = compiled.as_text()
+    assert text.startswith("HloModule jit__run_fused_impl")
+    for phase in ANNEAL_PHASES:
+        assert f"/{phase}/" in text, phase
+
+
 def test_corrupt_artifact_falls_back_to_fresh_compile(tmp_path, state, golden_artifact):
     raw = golden_artifact["data"]
     _install_artifact(tmp_path, golden_artifact, raw[: len(raw) // 2])  # torn

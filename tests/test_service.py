@@ -952,6 +952,44 @@ def test_trace_of_a_proposal_covers_monitor_analyzer_device(service):
     assert tid in {t.get("TraceId") for t in tasks["userTasks"]}
 
 
+def test_proposal_trace_names_each_host_stage(service):
+    """The stages inside the wrapper spans: the model build's three, the
+    request's option masks, the optimizer's device body's six, and the
+    operation's wait in the user-task pool under the root."""
+    status, payload = _poll(
+        service, "GET", "proposals", ignore_proposal_cache="true"
+    )
+    assert status == 200
+    status, trace, _ = _request(service, "GET", "trace", id=payload["_traceId"])
+    spans = _flatten_spans(trace["spans"])
+    by_id = {s["spanId"]: s for s in spans}
+
+    def parents(name):
+        return {
+            by_id[s["parentId"]]["name"]
+            for s in spans
+            if s["name"] == name and s["parentId"] in by_id
+        }
+
+    for stage in ("monitor.topology", "monitor.aggregate", "monitor.build_state"):
+        assert parents(stage) == {"monitor.cluster_model"}, stage
+    assert parents("analyzer.options") == {"service.proposals"}
+    for stage in (
+        "analyzer.validate", "analyzer.engine", "analyzer.report",
+        "engine.run", "analyzer.check", "analyzer.extract",
+    ):
+        assert parents(stage) == {"device.optimize"}, stage
+    # the pool wait: opened at submit, closed as the root opened
+    (queue,) = [s for s in spans if s["name"] == "tasks.queue"]
+    root = by_id[queue["parentId"]]
+    assert root["name"] == "service.proposals" and root["parentId"] is None
+    assert not queue["inFlight"]
+    assert queue["startOffsetMonoS"] <= root["startOffsetMonoS"]
+    # the anneal's round count rides the optimize span
+    (opt,) = [s for s in spans if s["name"] == "analyzer.optimize"]
+    assert opt["attributes"]["rounds"] >= 1
+
+
 def test_trace_index_and_unknown_id(service):
     _poll(service, "GET", "proposals")
     status, payload, _ = _request(service, "GET", "trace")

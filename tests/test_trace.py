@@ -103,6 +103,47 @@ def test_disabled_tracer_hands_out_noop():
     assert tr.current() is None
 
 
+def _host_events(trace_dir) -> list:
+    import glob
+    import os
+
+    from jax.profiler import ProfileData
+
+    (path,) = glob.glob(
+        os.path.join(trace_dir, "plugins", "profile", "*", "*.xplane.pb")
+    )
+    pd = ProfileData.from_file(path)
+    return [
+        ev.name
+        for plane in pd.planes
+        if plane.name.startswith("/host")
+        for line in plane.lines
+        for ev in line.events
+    ]
+
+
+def test_span_lands_on_the_profiler_host_plane(tmp_path):
+    """A context-manager span is a profiler annotation for its lifetime,
+    on the trace's own clock; a disabled tracer, and a start_span/finish
+    pair (which may cross threads), add no event."""
+    import jax
+
+    on, off = Tracer(), Tracer(enabled=False)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with on.span("analyzer.validate", component="analyzer"):
+            jax.numpy.ones(3).block_until_ready()
+        with off.span("monitor.aggregate", component="monitor"):
+            pass
+        on.start_span("tasks.queue", root=True).finish()
+    finally:
+        jax.profiler.stop_trace()
+    names = _host_events(tmp_path)
+    assert names.count("analyzer.validate") == 1
+    assert "monitor.aggregate" not in names
+    assert "tasks.queue" not in names
+
+
 def test_error_recorded_and_reraised():
     tr = Tracer()
     with pytest.raises(RuntimeError):
@@ -359,14 +400,19 @@ def test_profiler_trace_noop_without_dir():
     assert ran == [1, 2]
 
 
-def test_profiler_trace_survives_unwritable_dir():
+def test_profiler_trace_survives_unwritable_dir(tmp_path):
     """A profiler that cannot start must never fail the run it observes."""
     from cruise_control_tpu.common.profiling import profiler_trace
+
+    import jax
 
     ran = []
     with profiler_trace("/proc/definitely-not-writable/x"):
         ran.append(1)
     assert ran == [1]
+    # and it leaves the process's profiler free for the next trace
+    jax.profiler.start_trace(str(tmp_path))
+    jax.profiler.stop_trace()
 
 
 def test_device_gauges_register_and_read():
