@@ -595,11 +595,12 @@ FUSED_YS_KEYS = ("accepted", "ran", "stopped", "temperature", "cheap")
 
 #: the additional per-round keys the diagnostics-on fused program emits
 #: (OptimizerConfig.diagnostics): full-chain objective, per-goal violation
-#: vector [G], per-kind acceptance counts, and prior-draw usage — all
-#: read-only reductions riding the same single host extraction
+#: vector [G], per-kind acceptance counts, prior-draw usage, and the
+#: round's peak survivors in one step — all read-only reductions riding
+#: the same single host extraction
 FUSED_DIAG_YS_KEYS = FUSED_YS_KEYS + (
     "objective", "goal_viol", "acc_replica", "acc_swap", "acc_lead",
-    "prior_cands", "prior_acc",
+    "prior_cands", "prior_acc", "survivor_peak",
 )
 
 #: budget of AUTHORITATIVE (full goal chain) early-stop checks per run when
@@ -1013,6 +1014,16 @@ def _uniform_idx(key: jax.Array, shape, n: jax.Array) -> jax.Array:
     return jnp.minimum((u * n.astype(jnp.float32)).astype(jnp.int32), n - 1)
 
 
+def _compact_rows(mask: jax.Array, payload: dict, size: int):
+    """The rows of `payload` whose `mask` is set, in row order, packed
+    into `size` slots: (live mask [size], payload of [size, ...] columns).
+    Filler slots repeat the last row under a False mask.  The caller
+    guarantees that at most `size` rows are set."""
+    idx = jnp.nonzero(mask, size=size, fill_value=mask.shape[0] - 1)[0]
+    live = jnp.arange(size) < mask.sum()
+    return live, jax.tree.map(lambda x: x[idx], payload)
+
+
 #: The anneal's phases, as `jax.named_scope`s on the traced code: every
 #: op of an engine program carries the innermost phase it was traced
 #: under in its name stack (a profiler trace's `tf_op`), so a device trace
@@ -1251,6 +1262,18 @@ class Engine:
         n_sx = len(jax.tree.leaves(sx_av))
         donate = tuple(range(n_sx, len(leaves_av)))
         return leaves_av, in_def, donate
+
+    @property
+    def apply_rows(self) -> tuple[int, int]:
+        """Static caps (replica rows, leadership rows) of one step's
+        surviving apply rows.  `_select` keeps at most one survivor per
+        broker, and a survivor touches at least as many brokers as it
+        applies rows (a relocation: one row, two brokers; a swap: two
+        rows, two brokers; an intra-broker move: one row, one broker; a
+        leadership transfer: one row, two brokers), so a step applies at
+        most B replica-ext rows and B // 2 leadership rows."""
+        B = self.shape.B
+        return min(self.K_r + 2 * self.K_s, B), min(self.K_l, B // 2)
 
     def _ys_keys(self) -> tuple:
         """Per-round ys keys of this engine's (non-verbose) fused program
@@ -2499,6 +2522,8 @@ class Engine:
             & m_lead.any(axis=1)
             & dst_ok
             & sx.lead_ok[dst]
+            # two brokers per transfer: what `apply_rows` caps on
+            & (src != dst)
         )
 
         # load shift: rf leader->follower on src, rt follower->leader on dst
@@ -2807,7 +2832,9 @@ class Engine:
         sv_l = survive[nr + ns:]
         sv_r_ext = jnp.concatenate([sv_r, sv_s, sv_s])
 
-        carry = self._apply(sx, carry, sv_r_ext, prop["payr"], sv_l, prop["payl"])
+        carry = self._apply_survivors(
+            sx, carry, sv_r_ext, prop["payr"], sv_l, prop["payl"]
+        )
         carry = dataclasses.replace(carry, key=key)
         stats = dict(
             accepted=survive.sum(),
@@ -2829,6 +2856,21 @@ class Engine:
                 prior_acc=(sv_r & fp).sum(),
             )
         return carry, stats
+
+    def _apply_survivors(self, sx, carry: EngineCarry, sv_r, payr, sv_l, payl):
+        """`_apply` over the surviving rows only: each lane whose row
+        count exceeds its `apply_rows` cap is first compacted, in row
+        order, into cap-sized buffers, so the scatters visit at most the
+        cap and not every candidate.  Exact: the live rows reach the
+        scatters in the same order, and filler rows are dropped like
+        non-survivors.  Whether a lane compacts is decided by static
+        shapes alone."""
+        cap_r, cap_l = self.apply_rows
+        if cap_r < sv_r.shape[0]:
+            sv_r, payr = _compact_rows(sv_r, payr, cap_r)
+        if cap_l < sv_l.shape[0]:
+            sv_l, payl = _compact_rows(sv_l, payl, cap_l)
+        return self._apply(sx, carry, sv_r, payr, sv_l, payl)
 
     def _apply(
         self, sx, carry: EngineCarry, sv_r, payr, sv_l, payl,
@@ -3120,19 +3162,25 @@ class Engine:
             if diag
             else ("accepted",)
         )
+        round_keys = stat_keys + (("survivor_peak",) if diag else ())
 
         def do_round(carry, plan):
             temps = jnp.full((cfg.steps_per_round,), t_r, jnp.float32)
             carry, stats = self._scan_impl(sx, carry, temps, plan)
             carry, plan, cheap = self._round_prep_impl(sx, carry)
-            return carry, plan, cheap, {k: stats[k].sum() for k in stat_keys}
+            acc = {k: stats[k].sum() for k in stat_keys}
+            if diag:
+                # the most survivors one step of the round applied
+                # (bounded by `apply_rows`)
+                acc["survivor_peak"] = stats["accepted"].max()
+            return carry, plan, cheap, acc
 
         carry, plan, cheap_prev, acc = jax.lax.cond(
             run,
             do_round,
             lambda c, p: (
                 c, p, jnp.float32(jnp.inf),
-                {k: jnp.int32(0) for k in stat_keys},
+                {k: jnp.int32(0) for k in round_keys},
             ),
             carry,
             plan,
@@ -3163,7 +3211,7 @@ class Engine:
                 objective=obj_d, goal_viol=viol_d,
                 acc_replica=acc["acc_replica"], acc_swap=acc["acc_swap"],
                 acc_lead=acc["acc_lead"], prior_cands=acc["prior_cands"],
-                prior_acc=acc["prior_acc"],
+                prior_acc=acc["prior_acc"], survivor_peak=acc["survivor_peak"],
             )
         assert set(ys) == set(self._ys_keys()), (
             "fused ys keys drifted from FUSED_YS_KEYS/FUSED_DIAG_YS_KEYS — "
@@ -3333,6 +3381,7 @@ class Engine:
             device_s=round(device_s, 6),
             host_dispatch_s=round(time.monotonic() - t_start - device_s, 6),
             rounds=rounds_ran(ys["ran"]),
+            apply_rows=list(self.apply_rows),
         )
         conv = self._convergence_summary(ys)
         if conv is not None:
@@ -3447,6 +3496,7 @@ class Engine:
             objective_trajectory=[round(float(x), 8) for x in obj[ran]],
             temperatures=[float(x) for x in np.asarray(ys["temperature"])[ran]],
             accepted=[int(x) for x in np.asarray(ys["accepted"])[ran]],
+            survivor_peak=[int(x) for x in np.asarray(ys["survivor_peak"])[ran]],
             accepted_by_kind=dict(
                 replica=int(np.asarray(ys["acc_replica"])[ran].sum()),
                 swap=int(np.asarray(ys["acc_swap"])[ran].sum()),
@@ -3503,6 +3553,7 @@ class Engine:
             host_dispatch_s=round(t_disp - t_start, 6),
             device_s=round(t_sync - t_disp, 6),
             rounds=rounds_ran(ys["ran"]),
+            apply_rows=list(self.apply_rows),
         )
         conv = self._convergence_summary(ys)
         if conv is not None:
@@ -3644,6 +3695,7 @@ class Engine:
             host_dispatch_s=round(t_disp - t_start, 6),
             device_s=round(t_sync - t_disp, 6),
             rounds=rounds_ran(host["ys"]["ran"]),
+            apply_rows=list(self.apply_rows),
         )
         conv = self._convergence_summary(host["ys"])
         if conv is not None:
@@ -3763,5 +3815,6 @@ class Engine:
             timing=True, fused=False, blocking_syncs=sync["n"],
             device_s=round(sync["s"], 6),
             host_s=round(time.monotonic() - t_start - sync["s"], 6),
+            apply_rows=list(self.apply_rows),
         ))
         return self.carry_to_state(carry), history

@@ -759,6 +759,8 @@ class GoalOptimizer:
                         "mesh_shape", "collective_bytes",
                         # anneal rounds run (early stop, extra rounds)
                         "rounds",
+                        # static caps of one step's surviving apply rows
+                        "apply_rows",
                         # segmented (preemptible) execution under the
                         # device scheduler: how many wall-bounded slices
                         # this anneal dispatched as
@@ -1170,6 +1172,7 @@ class GoalOptimizer:
                     for k in (
                         "device_s", "blocking_syncs", "host_extract_s",
                         "scatter_width", "bucket", "convergence", "rounds",
+                        "apply_rows",
                     )
                     if timing.get(k) is not None
                 },

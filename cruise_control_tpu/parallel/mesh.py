@@ -646,6 +646,7 @@ class MeshEngine:
             mesh_shape=[self.n_restarts, self.n],
             collective_bytes=self.collective_bytes_per_round,
             rounds=rounds_ran(np.asarray(ys["ran"])[winner]),
+            apply_rows=list(self.engine.apply_rows),
         )
         if self.model_sharded:
             # only present when sharded — replicated-mode history records
@@ -653,18 +654,7 @@ class MeshEngine:
             timing["model_sharded"] = True
             timing["model_psum_bytes"] = int(self._twin.psum_bytes_per_round())
         if cfg.diagnostics:
-            # convergence summary with the SAME aggregation as the
-            # per-round history records above: COUNT fields sum over all
-            # chains (accepted == sum(kinds) holds, and the summary can be
-            # cross-checked against the round records), while STATE
-            # metrics (objective trajectory, final per-goal violations,
-            # ran/early-stop) are the winner chain's — the trajectory the
-            # served placement actually followed
-            win_ys = {k: np.asarray(v)[winner] for k, v in ys.items()}
-            for k in ("accepted", "acc_replica", "acc_swap", "acc_lead",
-                      "prior_cands", "prior_acc"):
-                win_ys[k] = np.asarray(ys[k]).sum(axis=0)
-            timing["convergence"] = self.engine._convergence_summary(win_ys)
+            timing["convergence"] = self._convergence_summary(ys, winner)
         history.append(timing)
         self.last_info = dict(
             objectives=objs, winner=winner,
@@ -877,6 +867,7 @@ class MeshEngine:
             mesh_shape=[self.n_restarts, self.n],
             collective_bytes=self.collective_bytes_per_round,
             rounds=rounds_ran(np.asarray(ys["ran"])[winner]),
+            apply_rows=list(self.engine.apply_rows),
         )
         if resume is not None:
             timing["resumed_from_round"] = int(resume.base)
@@ -884,17 +875,29 @@ class MeshEngine:
             timing["snapshots"] = seg_ctx.snapshots_taken
             timing["snapshot_s"] = round(seg_ctx.snapshot_seconds, 6)
         if cfg.diagnostics:
-            win_ys = {k: np.asarray(v)[winner] for k, v in ys.items()}
-            for k in ("accepted", "acc_replica", "acc_swap", "acc_lead",
-                      "prior_cands", "prior_acc"):
-                win_ys[k] = np.asarray(ys[k]).sum(axis=0)
-            timing["convergence"] = self.engine._convergence_summary(win_ys)
+            timing["convergence"] = self._convergence_summary(ys, winner)
         history.append(timing)
         self.last_info = dict(
             objectives=objs, winner=winner,
             n_chains=self.n_restarts, n_shards=self.n,
         )
         return state, history
+
+    def _convergence_summary(self, ys, winner: int) -> dict:
+        """The engine's convergence summary with the SAME aggregation as
+        the per-round history records: COUNT fields sum over all chains
+        (accepted == sum(kinds) holds, and the summary can be
+        cross-checked against the round records), the per-step survivor
+        peak is the largest over chains (comparable with the per-chain
+        cap `apply_rows`), while STATE metrics (objective trajectory,
+        final per-goal violations, ran/early-stop) are the winner chain's
+        — the trajectory the served placement actually followed."""
+        win_ys = {k: np.asarray(v)[winner] for k, v in ys.items()}
+        for k in ("accepted", "acc_replica", "acc_swap", "acc_lead",
+                  "prior_cands", "prior_acc"):
+            win_ys[k] = np.asarray(ys[k]).sum(axis=0)
+        win_ys["survivor_peak"] = np.asarray(ys["survivor_peak"]).max(axis=0)
+        return self.engine._convergence_summary(win_ys)
 
     def _history(self, ys, winner: int, cfg, verbose: bool) -> list[dict]:
         """Rebuild the plain engine's history shape from the winner
