@@ -29,9 +29,8 @@ def readings(config: dict, traffic: dict, seed: int, seconds: float) -> dict:
 
     result, dep = run.run_cell(config, traffic, seed, seconds, False, log=lambda m: None)
     served = run.checked_plans(result, seed, traffic)
-    control = correctness.Reference(dep, config, dtype=ml_dtypes.bfloat16)
-    sound = run.compare_plans(served, dep, config)
-    lowp = run.compare_plans(served, dep, config, control=control)
+    sound = run.compare_plans(result, served, dep, config)
+    lowp = run.compare_plans(result, served, dep, config, control_dtype=ml_dtypes.bfloat16)
     return {
         "seed": seed,
         "plans": len(result.done),

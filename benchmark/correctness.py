@@ -98,12 +98,15 @@ def served(snap: dict) -> Served:
 
 
 class Reference:
-    """The reference side for one deployment, shared by every plan."""
+    """The reference side for one deployment, shared by every plan;
+    `removed` lists the brokers the requests remove (dead in the reference)."""
 
-    def __init__(self, dep, config: dict, dtype=np.float64):
+    def __init__(self, dep, config: dict, dtype=np.float64, removed=()):
         self.dep = dep
         self.sem = ref.Semantics.from_config(config)
         self.dtype = dtype
+        self.alive = np.ones(dep.num_brokers, bool)
+        self.alive[list(removed)] = False
         self.leader, self.follower = ref.partition_loads(
             dep.window_values, dep.complete_windows, self.sem, dtype
         )
@@ -119,13 +122,13 @@ class Reference:
         d = self.dep
         return ref.goal_violations(
             pl, self.leader, self.follower, d.capacity, d.rack_of_broker,
-            d.part_topic, len(d.topic_names), self.sem, self.dtype,
+            d.part_topic, len(d.topic_names), self.sem, self.dtype, self.alive,
         )
 
     def objective(self, pl: ref.Placement, violations: dict) -> float:
         d = self.dep
         scores = ref.goal_scores(
-            pl, self.leader, self.follower, d.capacity, self.sem, self.dtype
+            pl, self.leader, self.follower, d.capacity, self.sem, self.dtype, self.alive
         )
         return ref.objective(violations, scores, self.sem)
 

@@ -13,6 +13,15 @@ semantics the configuration file states, it computes:
 * `broker_loads`, `goal_violations`, `balancedness`: per-broker loads, the
   violation of every goal of the chain, and the 0-100 balancedness of a
   placement;
+* `alive`: the brokers a request leaves in the cluster.  A broker the
+  request removes is dead, as in Cruise Control's `RemoveBrokersRunnable`:
+  OfflineReplicaGoal counts the replicas left on it (the verifier's
+  BROKEN_BROKERS check), and every capacity, count and distribution goal
+  judges the alive brokers alone, against the cluster's whole load spread
+  over them.  With every broker alive the readings stay, bit for bit,
+  those of a reference without the mask (pinned by
+  benchmark/tests/data/reference_all_alive.json), so a cell that removes
+  no broker reads the same;
 * `goal_scores`, `objective`: each distribution goal's dispersion (the
   coefficient of variation across brokers) and the one scalar the
   optimizer's anneal minimises: the violations weighted by priority, hard
@@ -204,13 +213,19 @@ def goal_violations(
     num_topics: int,
     sem: Semantics,
     dtype=np.float64,
+    alive=None,
 ) -> dict:
-    """{goal: violation} for every goal of the chain; every broker is alive.
+    """{goal: violation} for every goal of the chain; `alive` [B] marks the
+    brokers left in the cluster (default: all).
 
-    Violations are dimensionless: capacity excess over total capacity,
+    Violations are dimensionless: capacity excess over alive capacity,
     band excursions over the total of what is balanced, replica counts
-    over the number of replicas."""
+    over the number of replicas.  Averages spread the cluster's total over
+    the alive brokers (what a sound plan leaves on them), and only alive
+    brokers are held to a band or a capacity."""
     B = capacity.shape[0]
+    alive = np.ones(B, bool) if alive is None else np.asarray(alive, bool)
+    n_alive = int(alive.sum())
     P, rf = pl.brokers.shape
     n_rep = P * rf
     load = broker_loads(pl, leader_load, follower_load, B, dtype)
@@ -223,30 +238,31 @@ def goal_violations(
     out = {}
     for g in sem.goals:
         if g == "OfflineReplicaGoal":
-            v = 0.0  # no broker or disk is dead in these deployments
+            v = float((~alive)[flat_b].sum()) / n_rep  # no disk is dead here
         elif g == "RackAwareGoal":
             racks = int(rack_of_broker.max()) + 1
             cell = np.repeat(np.arange(P), rf) * racks + rack_of_broker[flat_b]
             per = np.bincount(cell, minlength=P * racks)
             v = float(np.maximum(per - 1, 0).sum()) / n_rep
         elif g == "ReplicaCapacityGoal":
-            v = float(np.maximum(rep_count - sem.max_replicas_per_broker, 0).sum()) / n_rep
+            excess = np.maximum(rep_count - sem.max_replicas_per_broker, 0)
+            v = float(excess[alive].sum()) / n_rep
         elif g in _CAPACITY_GOALS:
             r = _CAPACITY_GOALS[g]
             excess = np.maximum(load[:, r] - dtype(sem.capacity_threshold[r]) * cap[:, r], 0)
-            v = float(excess.sum()) / float(cap[:, r].sum())
+            v = float(excess[alive].sum()) / float(cap[alive, r].sum())
         elif g == "PotentialNwOutGoal":
             excess = np.maximum(
                 pot_nw_out - dtype(sem.capacity_threshold[NW_OUT]) * cap[:, NW_OUT], 0
             )
-            v = float(excess.sum()) / float(cap[:, NW_OUT].sum())
+            v = float(excess[alive].sum()) / float(cap[alive, NW_OUT].sum())
         elif g in _DISTRIBUTION_GOALS:
             r = _DISTRIBUTION_GOALS[g]
             t = sem.balance_threshold[r]
-            avg_pct = load[:, r].sum() / cap[:, r].sum()
-            upper = avg_pct * dtype(t) * cap[:, r]
-            lower = avg_pct * dtype(max(0.0, 2.0 - t)) * cap[:, r]
-            v = _band(load[:, r], upper, lower, load[:, r].sum())
+            avg_pct = load[:, r].sum() / cap[alive, r].sum()
+            upper = avg_pct * dtype(t) * cap[alive, r]
+            lower = avg_pct * dtype(max(0.0, 2.0 - t)) * cap[alive, r]
+            v = _band(load[alive, r], upper, lower, load[:, r].sum())
         elif g in ("ReplicaDistributionGoal", "LeaderReplicaDistributionGoal"):
             counts = rep_count if g == "ReplicaDistributionGoal" else lead_count
             t = (
@@ -254,22 +270,22 @@ def goal_violations(
                 if g == "ReplicaDistributionGoal"
                 else sem.leader_count_threshold
             )
-            upper, lower = _count_band(counts, t, B)
-            v = _band(counts, upper, lower, counts.sum())
+            upper, lower = _count_band(counts, t, n_alive)
+            v = _band(counts[alive], upper, lower, counts.sum())
         elif g == "TopicReplicaDistributionGoal":
             topic_of_rep = np.repeat(part_topic, rf)
             tc = np.bincount(topic_of_rep * B + flat_b, minlength=num_topics * B)
             tc = tc.reshape(num_topics, B)
             excursion = 0
             for row in tc:
-                upper, lower = _count_band(row, sem.topic_replica_threshold, B)
-                excursion += int(np.maximum(row - upper, 0).sum())
-                excursion += int(np.maximum(lower - row, 0).sum())
+                upper, lower = _count_band(row, sem.topic_replica_threshold, n_alive)
+                excursion += int(np.maximum(row[alive] - upper, 0).sum())
+                excursion += int(np.maximum(lower - row[alive], 0).sum())
             v = excursion / (float(tc.sum()) + 1e-12)
         elif g == "LeaderBytesInDistributionGoal":
-            avg = lead_bytes_in.sum() / B
+            avg = lead_bytes_in.sum() / n_alive
             upper = avg * dtype(sem.balance_threshold[NW_IN])
-            v = _band(lead_bytes_in, upper, 0, lead_bytes_in.sum())
+            v = _band(lead_bytes_in[alive], upper, 0, lead_bytes_in.sum())
         else:
             raise ValueError(f"no reference for goal {g!r}")
         out[g] = v
@@ -284,11 +300,13 @@ def _cv(values) -> float:
 
 
 def goal_scores(pl: Placement, leader_load, follower_load, capacity, sem: Semantics,
-                dtype=np.float64) -> dict:
-    """{goal: dispersion}: the coefficient of variation of what a
-    distribution goal balances (utilisation for a resource, replica and
-    leader counts, leader bytes in); 0 for every other goal."""
+                dtype=np.float64, alive=None) -> dict:
+    """{goal: dispersion}: the coefficient of variation, across the alive
+    brokers (default: all), of what a distribution goal balances
+    (utilisation for a resource, replica and leader counts, leader bytes
+    in); 0 for every other goal."""
     B = capacity.shape[0]
+    alive = np.ones(B, bool) if alive is None else np.asarray(alive, bool)
     load = broker_loads(pl, leader_load, follower_load, B, dtype)
     cap = capacity.astype(np.float64)
     counts = {
@@ -300,9 +318,9 @@ def goal_scores(pl: Placement, leader_load, follower_load, capacity, sem: Semant
     for g in sem.goals:
         if g in _DISTRIBUTION_GOALS:
             r = _DISTRIBUTION_GOALS[g]
-            out[g] = _cv(load[:, r].astype(np.float64) / (cap[:, r] + 1e-12))
+            out[g] = _cv((load[:, r].astype(np.float64) / (cap[:, r] + 1e-12))[alive])
         elif g in counts:
-            out[g] = _cv(counts[g])
+            out[g] = _cv(counts[g][alive])
         else:
             out[g] = 0.0
     return out

@@ -69,10 +69,30 @@ def resolve(workload: str) -> tuple[dict, dict, dict, dict]:
     if workload not in cells:
         raise BenchError(f"no workload {workload!r}; have {sorted(cells)}")
     cell = cells[workload]
+    return (bench, cell, *cell_files(bench, cell))
+
+
+def cell_files(bench: dict, cell: dict) -> tuple[dict, dict]:
+    """(configuration, traffic mix) that a cell names."""
     conf = next(c for c in bench["configs"] if c["name"] == cell["config"])
     config = load_json(conf["file"])
     traffic = load_json(os.path.join("benchmark", "traffic", f"{cell['traffic']}.json"))
-    return bench, cell, config, traffic
+    return config, traffic
+
+
+def removed_brokers(traffic: dict, num_brokers: int) -> list:
+    """The broker ids a mix's requests remove: with `"remove_brokers":
+    {"every": N}`, each id b of the deployment with b % N == 0."""
+    every = traffic.get("remove_brokers", {}).get("every")
+    return list(range(0, num_brokers, every)) if every else []
+
+
+def request_params(traffic: dict, removed: list) -> dict:
+    """The mix's query parameters, with `brokerid` filled from the removed brokers."""
+    params = {k: str(v) for k, v in traffic["params"].items()}
+    if removed:
+        params["brokerid"] = ",".join(map(str, removed))
+    return params
 
 
 def metric_reader(name: str):
@@ -192,6 +212,7 @@ class Run:
     compile_events: list = dataclasses.field(default_factory=list)
     setup_split: dict = dataclasses.field(default_factory=dict)
     readings: list = dataclasses.field(default_factory=list)  # compared numbers per checked plan
+    removed: list = dataclasses.field(default_factory=list)  # brokers each request removed
 
     @property
     def done(self) -> list:
@@ -332,7 +353,8 @@ def run_cell(config: dict, traffic: dict, seed: int, seconds: float,
     app.start()
     split["start_s"] = time.monotonic() - t
     client = Client(app)
-    params = {k: str(v) for k, v in traffic["params"].items()}
+    removed = removed_brokers(traffic, dep.num_brokers)
+    params = request_params(traffic, removed)
     method, endpoint = traffic["method"], traffic["endpoint"]
     try:
         # the precompute pass first, so the warm-up never races it for the
@@ -408,6 +430,7 @@ def run_cell(config: dict, traffic: dict, seed: int, seconds: float,
         setup_split=split,
         compile_events=[e for e in counter.events if start <= e[0]][: window_backend - setup_backend],
         traced=min(len(plans), traced or 0),
+        removed=removed,
     )
     if trace:
         from benchmark.trace_reduce import reduce_trace_dir
@@ -439,12 +462,17 @@ def checked_plans(run: Run, seed: int, traffic: dict) -> list:
     return out
 
 
-def compare_plans(served: list, dep, config: dict, control=None) -> list:
-    """One reading of the compared numbers per plan (see correctness.py);
-    `control` is a lower-precision Reference put in the program's place."""
+def compare_plans(run: Run, served: list, dep, config: dict, control_dtype=None) -> list:
+    """One reading of the compared numbers per plan (see correctness.py),
+    with the brokers the run's requests removed dead in the reference;
+    `control_dtype` puts the reference, in that precision, in the
+    program's place."""
     from benchmark import correctness
 
-    reference = correctness.Reference(dep, config)
+    reference = correctness.Reference(dep, config, removed=run.removed)
+    control = None if control_dtype is None else correctness.Reference(
+        dep, config, dtype=control_dtype, removed=run.removed
+    )
     return [correctness.compare(s, reference, control) for s in served]
 
 
@@ -518,7 +546,7 @@ def main(argv=None) -> int:
 
     run, dep = run_cell(config, traffic, args.seed, args.seconds, bool(args.trace), log)
     limits = config["limits"]
-    readings = compare_plans(checked_plans(run, args.seed, traffic), dep, config)
+    readings = compare_plans(run, checked_plans(run, args.seed, traffic), dep, config)
     run.readings = readings
     worst = correctness.worst(readings) if readings else {}
     bal = (
