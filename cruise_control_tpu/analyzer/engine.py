@@ -273,11 +273,9 @@ class EngineCarry:
         "part_replicas",
         "alive",
         "dest_ids",
-        "dest_ok",
-        "lead_ok",
         "topic_movable",
-        "host_multi",
-        "host_cap",
+        "broker_static_f",
+        "broker_static_i",
         "total_cap",
         "n_alive",
         "n_valid",
@@ -298,11 +296,12 @@ class EngineStatics:
     part_replicas: jax.Array  # i32[P, max_rf]
     alive: jax.Array  # bool[B] valid & alive
     dest_ids: jax.Array  # i32[B] allowed destination ids, cyclically padded
-    dest_ok: jax.Array  # bool[B] allowed-destination mask (swap feasibility)
-    lead_ok: jax.Array  # bool[B]
     topic_movable: jax.Array  # bool[T]
-    host_multi: jax.Array  # bool[H]
-    host_cap: jax.Array  # f32[H, 4]
+    #: the statics columns of the packed per-broker table
+    #: (`broker_row_columns`, `Engine._broker_rows`), built once per model
+    #: generation: f32[B, 8 + D] and i32[B, 7 + D]
+    broker_static_f: jax.Array
+    broker_static_i: jax.Array
     total_cap: jax.Array  # f32[4]
     n_alive: jax.Array  # f32 scalar
     n_valid: jax.Array  # f32 scalar
@@ -333,6 +332,61 @@ class EngineStatics:
     #: taken from the prior CDF instead of uniform; 0.0 (cold prior) makes
     #: the destination stream byte-identical to the uniform-only draw
     prior_mix: jax.Array
+
+
+def broker_row_columns(D: int) -> tuple[tuple, tuple]:
+    """Column layout of the packed per-broker table that goal-delta
+    scoring reads: one f32 and one i32 row per broker holding every
+    per-broker value a candidate's score reads.  On a TPU a gather is
+    issued index by index and costs about the same for a 20-word row as
+    for one word, so each move kind gathers its source and destination
+    rows once instead of ~16 columns apart.
+
+    (f32 columns, i32 columns), each a tuple of (name, width) in column
+    order, D the logdir count; a width of None is one column read without
+    its trailing axis.  The carry columns lead and are rewritten every
+    step (`Engine._broker_rows`); the statics columns follow, built once
+    per model generation (`build_statics`).  The i32 table carries
+    integers and masks (0/1) exactly."""
+    f32 = (
+        ("load", NUM_RESOURCES), ("pot", None), ("lbin", None),
+        ("host_load", NUM_RESOURCES), ("disk_load", D),
+        # statics
+        ("cap", NUM_RESOURCES), ("host_cap", NUM_RESOURCES), ("disk_cap", D),
+    )
+    i32 = (
+        ("rcount", None), ("lcount", None),
+        # statics
+        ("alive", None), ("broker_alive", None), ("host", None), ("rack", None),
+        ("host_multi", None), ("dest_ok", None), ("lead_ok", None), ("disk_alive", D),
+    )
+    return f32, i32
+
+
+_BROKER_ROW_MASKS = frozenset(
+    {"alive", "broker_alive", "host_multi", "dest_ok", "lead_ok", "disk_alive"}
+)
+
+
+def broker_row_fields(rows_f: jax.Array, rows_i: jax.Array, D: int) -> dict:
+    """{name: column} of packed broker rows of any leading shape (the
+    whole table, or rows gathered at candidate brokers)."""
+    out = {}
+    for table, cols in zip((rows_f, rows_i), broker_row_columns(D)):
+        at = 0
+        for name, width in cols:
+            col = table[..., at:at + (width or 1)]
+            if width is None:
+                col = col[..., 0]
+            out[name] = col != 0 if name in _BROKER_ROW_MASKS else col
+            at += width or 1
+    return out
+
+
+def _pick_disk(cols: jax.Array, d: jax.Array) -> jax.Array:
+    """cols[k, d[k]] of a [K, D] mask, as a select along the row's D
+    columns (the row is already gathered; this issues no gather)."""
+    return (cols & (jnp.arange(cols.shape[-1]) == d[..., None])).any(-1)
 
 
 @partial(
@@ -454,8 +508,8 @@ def build_statics(
     s = state.shape
     h_keys = (
         "broker_valid", "broker_alive", "broker_capacity", "broker_host",
-        "disk_alive", "disk_capacity", "replica_valid", "replica_partition",
-        "replica_pos",
+        "broker_rack", "disk_alive", "disk_capacity", "replica_valid",
+        "replica_partition", "replica_pos",
     )
     h = dict(zip(h_keys, jax.device_get(tuple(getattr(state, k) for k in h_keys))))
     alive = h["broker_valid"] & h["broker_alive"]
@@ -474,6 +528,7 @@ def build_statics(
     bph = np.bincount(host[valid_b], minlength=s.num_hosts)
     host_cap = np.zeros((s.num_hosts, NUM_RESOURCES), np.float32)
     np.add.at(host_cap, host[valid_b & alive], cap[valid_b & alive])
+    lead = alive & options.leadership_allowed(state)
     dmask = h["disk_alive"] & alive[:, None]
     # shape-invariant sampling bounds: uniform draws cover only the valid
     # replica prefix / real destination list, so the padded sizes never
@@ -497,11 +552,22 @@ def build_statics(
         part_replicas=jnp.asarray(partition_replica_table(state, host=h)),
         alive=jnp.asarray(alive),
         dest_ids=jnp.asarray(dest_pad),
-        dest_ok=jnp.asarray(dest),
-        lead_ok=jnp.asarray(alive & options.leadership_allowed(state)),
         topic_movable=jnp.asarray(options.topic_movable(state)),
-        host_multi=jnp.asarray(bph > 1),
-        host_cap=jnp.asarray(host_cap),
+        # the statics columns of broker_row_columns, in its order
+        broker_static_f=jnp.asarray(np.concatenate(
+            [cap, host_cap[host], h["disk_capacity"]],
+            axis=1, dtype=np.float32,
+        )),
+        broker_static_i=jnp.asarray(np.concatenate(
+            [
+                np.stack([
+                    alive, h["broker_alive"], host, h["broker_rack"],
+                    (bph > 1)[host], dest, lead,
+                ], axis=1).astype(np.int32),
+                h["disk_alive"].astype(np.int32),
+            ],
+            axis=1,
+        )),
         total_cap=jnp.asarray((cap * alive[:, None]).sum(0) + 1e-12, dtype=jnp.float32),
         n_alive=jnp.asarray(max(1.0, float(alive.sum())), jnp.float32),
         n_valid=jnp.asarray(
@@ -1590,10 +1656,9 @@ class Engine:
         terms + rack + offline + tie), NOT the full goal-chain evaluation.
         """
         g = self._globals(sx, carry)
-        b = jnp.arange(self.shape.B)
         terms = self._broker_terms(
             sx,
-            b,
+            self._all_rows(g),
             carry.broker_load,
             carry.broker_replica_count,
             carry.broker_leader_count,
@@ -1753,12 +1818,10 @@ class Engine:
         by the plain engine and the model-sharded twin.  `extra` (f32[B])
         adds to the broker weights (a repair round's rack term)."""
         st = sx.state
-        B = self.shape.B
         g = self._globals(sx, carry)
-        b = jnp.arange(B)
         w = self._broker_terms(
             sx,
-            b,
+            self._all_rows(g),
             carry.broker_load,
             carry.broker_replica_count,
             carry.broker_leader_count,
@@ -1858,11 +1921,51 @@ class Engine:
             total_disk_load=total_disk_load + 1e-12,
             pct_sum=pct.sum(0),  # [4]
             pct_sumsq=(pct * pct).sum(0),  # [4]
+            rows=self._broker_rows(sx, carry),
         )
 
-    def _broker_terms(self, sx, b, load, rcount, lcount, pot, lbin, g):
-        """Weighted objective contribution of broker(s) b given hypothetical
-        per-broker stats.  All inputs may carry a leading candidate axis.
+    def _broker_rows(self, sx: EngineStatics, carry: EngineCarry):
+        """The packed per-broker table (`broker_row_columns`) of this step:
+        the carry's columns written beside the statics' columns.  O(B·F)
+        once a step; every per-broker read of candidate scoring then slices
+        a row gathered once per index vector (`_rows_at`).  Every broker
+        leaf it reads is replicated on the candidate mesh and in the
+        model-sharded engine, so the table costs no collective."""
+        rows_f = jnp.concatenate(
+            [
+                carry.broker_load,
+                carry.broker_potential_nw_out[:, None],
+                carry.broker_leader_bytes_in[:, None],
+                carry.host_load[sx.state.broker_host],
+                carry.disk_load,
+                sx.broker_static_f,
+            ],
+            axis=1,
+        )
+        rows_i = jnp.concatenate(
+            [
+                carry.broker_replica_count[:, None],
+                carry.broker_leader_count[:, None],
+                sx.broker_static_i,
+            ],
+            axis=1,
+        )
+        return rows_f, rows_i
+
+    def _rows_at(self, g, b):
+        """{field: value} of the brokers `b` (any shape): ONE gather of
+        each packed table at b."""
+        rows_f, rows_i = g["rows"]
+        return broker_row_fields(rows_f[b], rows_i[b], self.shape.max_disks_per_broker)
+
+    def _all_rows(self, g):
+        """{field: column} of every broker, read from the table in place."""
+        return broker_row_fields(*g["rows"], self.shape.max_disks_per_broker)
+
+    def _broker_terms(self, sx, rows, load, rcount, lcount, pot, lbin, g):
+        """Weighted objective contribution of the brokers whose packed rows
+        are `rows` (`_rows_at` / `_all_rows`) given hypothetical per-broker
+        stats.  All inputs may carry a leading candidate axis.
 
         Mirrors (in delta-decomposable form): CapacityGoal (broker
         granularity), ReplicaCapacityGoal, PotentialNwOutGoal,
@@ -1870,11 +1973,10 @@ class Engine:
         LeaderBytesInDistributionGoal — see the goal classes for the
         reference citations.
         """
-        st = sx.state
         w = self.w
         c = self.constraint
-        cap = st.broker_capacity[b]  # [..., 4]
-        alive = sx.alive[b]
+        cap = rows["cap"]  # [..., 4]
+        alive = rows["alive"]
         # mixed-precision accumulation (config analyzer.precision.score.dtype):
         # each goal term is still computed in f32 (the reluses against
         # capacities need the dynamic range), but the running per-broker SUM
@@ -1885,7 +1987,7 @@ class Engine:
         lowp = self.config.score_dtype != "float32"
         acc_dt = jnp.dtype(self.config.score_dtype)
         _acc = (lambda x: x.astype(acc_dt)) if lowp else (lambda x: x)
-        out = jnp.zeros(jnp.shape(b), acc_dt if lowp else jnp.float32)
+        out = jnp.zeros(jnp.shape(alive), acc_dt if lowp else jnp.float32)
         # per-resource constants as [4] vectors: one vectorized expression
         # instead of a 4-iteration Python loop — this function is inlined
         # ~8x into the step program, so per-resource unrolling multiplies
@@ -1898,7 +2000,7 @@ class Engine:
 
         # capacity goals (broker granularity; host granularity handled in
         # _host_terms for multi-broker hosts)
-        single = ~sx.host_multi[st.broker_host[b]]
+        single = ~rows["host_multi"]
         excess = _relu(load - cth * cap)  # [..., 4]
         gate = alive[..., None] & (single[..., None] | ~host_res)
         out += _acc((jnp.where(gate, excess, 0.0) * (w_cap / sx.total_cap)).sum(-1))
@@ -1951,12 +2053,11 @@ class Engine:
         # expect f32; a no-op when the accumulator already is
         return out.astype(jnp.float32)
 
-    def _host_terms(self, sx, h, hload):
+    def _host_terms(self, sx, hcap, multi, hload):
         """Host-granularity capacity terms for multi-broker hosts
-        (reference CapacityGoal host/broker split)."""
+        (reference CapacityGoal host/broker split), of hosts whose capacity
+        and multi-broker flag are `hcap` / `multi` (read off broker rows)."""
         c = self.constraint
-        hcap = sx.host_cap[h]
-        multi = sx.host_multi[h]
         # vectorized over resources (see _broker_terms): host resources only
         w_cap = np.asarray(
             [
@@ -1971,19 +2072,19 @@ class Engine:
             jnp.where(multi[..., None], excess, 0.0) * (w_cap / sx.total_cap)
         ).sum(-1)
 
-    def _disk_terms(self, sx, b, disk_row, broker_disk_load, g):
-        """Intra-broker disk goal terms for broker(s) b.
+    def _disk_terms(self, sx, rows, disk_row, broker_disk_load, g):
+        """Intra-broker disk goal terms for the brokers whose packed rows
+        are `rows`.
 
-        disk_row: hypothetical f32[..., D] per-logdir load of broker b.
+        disk_row: hypothetical f32[..., D] per-logdir load of each broker.
         broker_disk_load: its sum (for the per-broker distribution band).
         """
-        st = sx.state
         w = self.w
         if w.intra_cap == 0.0 and w.intra_dist == 0.0:
-            return jnp.zeros(jnp.shape(b), jnp.float32)
-        dcap = st.disk_capacity[b]  # [..., D]
-        dalive = st.disk_alive[b] & sx.alive[b][..., None]
-        out = jnp.zeros(jnp.shape(b), jnp.float32)
+            return jnp.zeros(jnp.shape(rows["alive"]), jnp.float32)
+        dcap = rows["disk_cap"]  # [..., D]
+        dalive = rows["disk_alive"] & rows["alive"][..., None]
+        out = jnp.zeros(jnp.shape(rows["alive"]), jnp.float32)
         # IntraBrokerDiskCapacityGoal
         cap_term = jnp.where(
             dalive, _relu(disk_row - self.d_thresh * dcap), disk_row
@@ -2169,7 +2270,6 @@ class Engine:
         self, sx, carry: EngineCarry, key: jax.Array, g, plan=None, slice_=None
     ):
         """K_r replica-move candidates -> (delta, src, dst, part, payload)."""
-        st = sx.state
         K = self.K_r
         with anneal_phase("anneal_sample"):
             k1, k2 = jax.random.split(key)
@@ -2193,9 +2293,14 @@ class Engine:
         rows = self._take_rows(sx, carry, r, tuple(fields))
         src = rows["broker"]
         part = rows["part"]
+        # the source and destination brokers' packed rows: one gather of
+        # each table
+        br = self._rows_at(g, jnp.stack([src, dst]))
+        bs = {f: v[0] for f, v in br.items()}
+        bd = {f: v[1] for f, v in br.items()}
 
         # feasibility (reference GoalUtils.legitMove:153 + exclusions)
-        offline = ~(st.broker_alive[src] & st.disk_alive[src, rows["disk"]])
+        offline = ~(bs["broker_alive"] & _pick_disk(bs["disk_alive"], rows["disk"]))
         movable = sx.topic_movable[rows["topic"]] | offline
         feasible = rows["valid"] & movable & (src != dst)
         # no second replica of the partition on dst (reference
@@ -2211,8 +2316,8 @@ class Engine:
         load = jnp.where(rows["valid"][:, None], load, 0.0)
 
         # destination logdir: most-free alive disk on dst
-        ddst_pct = carry.disk_load[dst] / (st.disk_capacity[dst] + 1e-12)
-        ddst_pct = jnp.where(st.disk_alive[dst], ddst_pct, jnp.inf)
+        ddst_pct = bd["disk_load"] / (bd["disk_cap"] + 1e-12)
+        ddst_pct = jnp.where(bd["disk_alive"], ddst_pct, jnp.inf)
         d_dst = jnp.argmin(ddst_pct, axis=1).astype(jnp.int32)
         d_src = rows["disk"]
 
@@ -2223,10 +2328,8 @@ class Engine:
 
         delta = self._move_delta(
             sx,
-            carry,
             g,
-            src=src,
-            dst=dst,
+            br,
             dload_src=-load,
             dload_dst=load,
             dcount=dcount,
@@ -2239,7 +2342,7 @@ class Engine:
         )
 
         # rack cells (reference RackAwareGoal)
-        rack_s, rack_d = st.broker_rack[src], st.broker_rack[dst]
+        rack_s, rack_d = bs["rack"], bd["rack"]
         c_s = self._rack_cell(carry, part, rack_s)
         c_d = self._rack_cell(carry, part, rack_d)
         drack = (_relu(c_s - 2.0) - _relu(c_s - 1.0)) + (_relu(c_d) - _relu(c_d - 1.0))
@@ -2261,7 +2364,7 @@ class Engine:
             delta += self.w.topic_dist * dtop / g["total_count"]
 
         # offline-replica term (reference OptimizationVerifier BROKEN_BROKERS)
-        dst_ok = st.broker_alive[dst] & st.disk_alive[dst, d_dst]
+        dst_ok = bd["broker_alive"] & _pick_disk(bd["disk_alive"], d_dst)
         doff = (~dst_ok).astype(jnp.float32) - offline.astype(jnp.float32)
         delta += self.w.offline * doff / sx.n_valid
 
@@ -2304,7 +2407,6 @@ class Engine:
         so `_apply`'s broker-axis scatters cancel and only replica_disk +
         disk_load actually change.
         """
-        st = sx.state
         K = self.K_r
         D = self.shape.max_disks_per_broker
         r = self._slice_draws(slice_, self._sample_sources(sx, key, K, plan))
@@ -2317,16 +2419,18 @@ class Engine:
         d_src = rows["disk"]
         part = rows["part"]
 
+        bb = self._rows_at(g, b)  # the broker's packed rows
+
         # destination logdir: most-free alive disk on b, excluding the
         # current slot
-        pct = carry.disk_load[b] / (st.disk_capacity[b] + 1e-12)
-        pct = jnp.where(st.disk_alive[b], pct, jnp.inf)
+        pct = bb["disk_load"] / (bb["disk_cap"] + 1e-12)
+        pct = jnp.where(bb["disk_alive"], pct, jnp.inf)
         pct = jnp.where(jax.nn.one_hot(d_src, D, dtype=bool), jnp.inf, pct)
         d_dst = jnp.argmin(pct, axis=1).astype(jnp.int32)
 
-        off_src = ~(st.broker_alive[b] & st.disk_alive[b, d_src])
+        off_src = ~(bb["broker_alive"] & _pick_disk(bb["disk_alive"], d_src))
         movable = sx.topic_movable[rows["topic"]] | off_src
-        dst_ok = st.broker_alive[b] & st.disk_alive[b, d_dst]
+        dst_ok = bb["broker_alive"] & _pick_disk(bb["disk_alive"], d_dst)
         feasible = (
             rows["valid"] & movable & dst_ok & (d_dst != d_src)
         )
@@ -2339,14 +2443,14 @@ class Engine:
         ddisk = load[:, int(Resource.DISK)]
 
         # intra-broker disk terms: one broker, one row reshuffled
-        row = carry.disk_load[b]
+        row = bb["disk_load"]
         shift = (
             jax.nn.one_hot(d_dst, D, dtype=jnp.float32)
             - jax.nn.one_hot(d_src, D, dtype=jnp.float32)
         ) * ddisk[:, None]
         bsum = row.sum(-1)
-        delta = self._disk_terms(sx, b, row + shift, bsum, g) - self._disk_terms(
-            sx, b, row, bsum, g
+        delta = self._disk_terms(sx, bb, row + shift, bsum, g) - self._disk_terms(
+            sx, bb, row, bsum, g
         )
         # offline-replica shift (rescuing off a failed logdir)
         delta += self.w.offline * (
@@ -2385,7 +2489,6 @@ class Engine:
         Returns (delta, feasible, src, dst, part_r, part_q, payload); the
         surviving swaps are applied as two linked relocation payload rows.
         """
-        st = sx.state
         K = self.K_s
         if K == 0:
             z = jnp.zeros((0,), jnp.float32)
@@ -2422,8 +2525,12 @@ class Engine:
 
         d_r = rows_r["disk"]
         d_q = rows_q["disk"]
-        off_r = ~(st.broker_alive[src] & st.disk_alive[src, d_r])
-        off_q = ~(st.broker_alive[dst] & st.disk_alive[dst, d_q])
+        # the two brokers' packed rows: one gather of each table
+        br = self._rows_at(g, jnp.stack([src, dst]))
+        bs = {f: v[0] for f, v in br.items()}
+        bd = {f: v[1] for f, v in br.items()}
+        off_r = ~(bs["broker_alive"] & _pick_disk(bs["disk_alive"], d_r))
+        off_q = ~(bd["broker_alive"] & _pick_disk(bd["disk_alive"], d_q))
         movable_r = sx.topic_movable[rows_r["topic"]] | off_r
         movable_q = sx.topic_movable[rows_q["topic"]] | off_q
         feasible = (
@@ -2434,13 +2541,13 @@ class Engine:
             & (src != dst)
             & (part_r != part_q)
             # both ends must be allowed destinations (each receives a replica)
-            & sx.dest_ok[src]
-            & sx.dest_ok[dst]
+            & bs["dest_ok"]
+            & bd["dest_ok"]
             # each replica inherits the other's disk slot — that slot must be
             # alive (relocations argmin over alive disks; swaps must not be
             # the back door onto a failed logdir)
-            & st.disk_alive[dst, d_q]
-            & st.disk_alive[src, d_r]
+            & _pick_disk(bd["disk_alive"], d_q)
+            & _pick_disk(bs["disk_alive"], d_r)
         )
         # neither partition may end up duplicated on its new broker
         mem_r = self._take_members(sx, part_r)  # [K, max_rf]
@@ -2469,10 +2576,8 @@ class Engine:
         # r -> (dst, q's disk slot), q -> (src, r's disk slot)
         delta = self._move_delta(
             sx,
-            carry,
             g,
-            src=src,
-            dst=dst,
+            br,
             dload_src=load_q - load_r,
             dload_dst=load_r - load_q,
             dcount=jnp.zeros(r.shape, jnp.int32),
@@ -2485,7 +2590,7 @@ class Engine:
         )
 
         # rack cells for both partitions (reference RackAwareGoal)
-        rack_s, rack_d = st.broker_rack[src], st.broker_rack[dst]
+        rack_s, rack_d = bs["rack"], bd["rack"]
 
         def rack_delta(part, rack_from, rack_to):
             c_f = self._rack_cell(carry, part, rack_from)
@@ -2518,8 +2623,8 @@ class Engine:
             ) / g["total_count"]
 
         # offline-replica shifts for both replicas
-        r_ok = st.broker_alive[dst] & st.disk_alive[dst, d_q]
-        q_ok = st.broker_alive[src] & st.disk_alive[src, d_r]
+        r_ok = bd["broker_alive"] & _pick_disk(bd["disk_alive"], d_q)
+        q_ok = bs["broker_alive"] & _pick_disk(bs["disk_alive"], d_r)
         doff = (
             (~r_ok).astype(jnp.float32)
             - off_r.astype(jnp.float32)
@@ -2568,7 +2673,6 @@ class Engine:
         self, sx, carry: EngineCarry, key: jax.Array, g, plan=None, slice_=None
     ):
         """K_l leadership-transfer candidates (reference relocateLeadership:374)."""
-        st = sx.state
         K = self.K_l
         R = self.shape.R
         if K == 0:
@@ -2598,13 +2702,17 @@ class Engine:
         )
 
         src, dst = rows_f["broker"], rows_t["broker"]
-        dst_ok = st.broker_alive[dst] & st.disk_alive[dst, rows_t["disk"]]
+        # the two brokers' packed rows: one gather of each table
+        br = self._rows_at(g, jnp.stack([src, dst]))
+        bs = {f: v[0] for f, v in br.items()}
+        bd = {f: v[1] for f, v in br.items()}
+        dst_ok = bd["broker_alive"] & _pick_disk(bd["disk_alive"], rows_t["disk"])
         feasible = (
             rows_t["valid"]
             & ~rows_t["is_lead"]
             & m_lead.any(axis=1)
             & dst_ok
-            & sx.lead_ok[dst]
+            & bd["lead_ok"]
             # two brokers per transfer: what `apply_rows` caps on
             & (src != dst)
         )
@@ -2616,10 +2724,8 @@ class Engine:
         # NOTE: src loses rf's leader NW_IN; handled via asymmetric lbin deltas
         delta = self._move_delta(
             sx,
-            carry,
             g,
-            src=src,
-            dst=dst,
+            br,
             dload_src=dl_f,
             dload_dst=dl_t,
             dcount=jnp.zeros(rt.shape, jnp.int32),
@@ -2634,7 +2740,7 @@ class Engine:
         )
 
         if self.w.pref_leader != 0.0:
-            src_ok = st.broker_alive[src] & st.disk_alive[src, rows_f["disk"]]
+            src_ok = bs["broker_alive"] & _pick_disk(bs["disk_alive"], rows_f["disk"])
             pref_f = (rows_f["pos"] == 0) & src_ok  # rf becomes violating
             pref_t = (rows_t["pos"] == 0) & dst_ok  # rt stops violating
             delta += (
@@ -2662,11 +2768,9 @@ class Engine:
     def _move_delta(
         self,
         sx,
-        carry,
         g,
+        br,
         *,
-        src,
-        dst,
         dload_src,
         dload_dst,
         dcount,
@@ -2679,38 +2783,31 @@ class Engine:
         dlbin_src=None,
         ddisk_src=None,
     ):
-        """Objective delta for candidates touching brokers (src, dst).
+        """Objective delta for candidates touching brokers (src, dst), whose
+        packed rows `br` (`_rows_at` of the [2, K] stack of src and dst)
+        hold every per-broker value read here.
 
         dload_src is ADDED to src (callers pass negative values to remove
         load); dload_dst is added to dst.  dcount/dlcount/dpot/dlbin move
         from src to dst unless an asymmetric *_src override is given.
         """
-        st = sx.state
         if dlbin_src is None:
             dlbin_src = dlbin
         if ddisk_src is None:
             ddisk_src = ddisk
-
-        def gather(b):
-            return (
-                carry.broker_load[b],
-                carry.broker_replica_count[b],
-                carry.broker_leader_count[b],
-                carry.broker_potential_nw_out[b],
-                carry.broker_leader_bytes_in[b],
-            )
-
-        ls, rs, lcs, ps, lbs = gather(src)
-        ld, rd, lcd, pd, lbd = gather(dst)
+        ls, ld = br["load"]
+        rs, rd = br["rcount"]
+        lcs, lcd = br["lcount"]
+        ps, pd = br["pot"]
+        lbs, lbd = br["lbin"]
         # ONE stacked _broker_terms call over a [4, K] lane axis
         # (src-old, dst-old, src-new, dst-new) instead of four separate
         # inlines: element-wise identical math, but the traced step program
         # shrinks by ~1.5k equations — warm-start trace time is paced by
         # graph size (this helper is reached from all three candidate kinds)
-        b4 = jnp.stack([src, dst, src, dst])
         t4 = self._broker_terms(
             sx,
-            b4,
+            {f: jnp.concatenate([v, v]) for f, v in br.items()},
             jnp.stack([ls, ld, ls + dload_src, ld + dload_dst]),
             jnp.stack([rs, rd, rs - dcount, rd + dcount]),
             jnp.stack([lcs, lcd, lcs - dlcount, lcd + dlcount]),
@@ -2720,20 +2817,23 @@ class Engine:
         )
         delta = (t4[2] + t4[3]) - (t4[0] + t4[1])
 
+        def ssdd(v):  # [2, K, ...] -> lanes (src, src, dst, dst)
+            return jnp.stack([v[0], v[0], v[1], v[1]])
+
         # host-granularity capacity (same-host moves cancel)
-        h_s, h_d = st.broker_host[src], st.broker_host[dst]
-        hl_s, hl_d = carry.host_load[h_s], carry.host_load[h_d]
+        hl_s, hl_d = br["host_load"]
         th4 = self._host_terms(
             sx,
-            jnp.stack([h_s, h_s, h_d, h_d]),
+            ssdd(br["host_cap"]),
+            ssdd(br["host_multi"]),
             jnp.stack([hl_s + dload_src, hl_s, hl_d + dload_dst, hl_d]),
         )
         dh = th4[0] - th4[1] + th4[2] - th4[3]
-        delta += jnp.where(h_s != h_d, dh, 0.0)
+        delta += jnp.where(br["host"][0] != br["host"][1], dh, 0.0)
 
         # intra-broker disk goals
         if self.w.intra_cap != 0.0 or self.w.intra_dist != 0.0:
-            row_s, row_d = carry.disk_load[src], carry.disk_load[dst]
+            row_s, row_d = br["disk_load"]
             D = self.shape.max_disks_per_broker
             oh_s = jax.nn.one_hot(d_src, D, dtype=jnp.float32)
             oh_d = jax.nn.one_hot(d_dst, D, dtype=jnp.float32)
@@ -2742,7 +2842,7 @@ class Engine:
             bsum_s, bsum_d = row_s.sum(-1), row_d.sum(-1)
             td4 = self._disk_terms(
                 sx,
-                jnp.stack([src, src, dst, dst]),
+                {f: ssdd(v) for f, v in br.items()},
                 jnp.stack([row_s2, row_s, row_d2, row_d]),
                 jnp.stack([bsum_s - ddisk_src, bsum_s, bsum_d + ddisk, bsum_d]),
                 g,
@@ -2750,12 +2850,12 @@ class Engine:
             delta += td4[0] - td4[1] + td4[2] - td4[3]
 
         # dispersion tiebreaker via sufficient statistics
-        cap_s = st.broker_capacity[src] + 1e-12
-        cap_d = st.broker_capacity[dst] + 1e-12
+        cap_s = br["cap"][0] + 1e-12
+        cap_d = br["cap"][1] + 1e-12
         p_s, p_d = ls / cap_s, ld / cap_d
         p_s2, p_d2 = (ls + dload_src) / cap_s, (ld + dload_dst) / cap_d
-        a_s = sx.alive[src][:, None].astype(jnp.float32)
-        a_d = sx.alive[dst][:, None].astype(jnp.float32)
+        a_s = br["alive"][0][:, None].astype(jnp.float32)
+        a_d = br["alive"][1][:, None].astype(jnp.float32)
         dsum = a_s * (p_s2 - p_s) + a_d * (p_d2 - p_d)
         dsumsq = a_s * (p_s2**2 - p_s**2) + a_d * (p_d2**2 - p_d**2)
         delta += self._tie_term(
